@@ -221,6 +221,8 @@ def _gate_bench7(verbose: bool) -> None:
 
 def run(verbose: bool = True, quick: bool = False, fresh: bool = False,
         workers: int = 0):
+    from repro.sweep.engine import refuse_pool_on_tpu
+    refuse_pool_on_tpu(workers)  # before any cell, not after the serial run
     encode = _encode_bench()
     assert encode["q_bitexact"], "batched codec broke int8 wire parity"
     assert encode["wire_bytes_identical"], \

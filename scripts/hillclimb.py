@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """§Perf hillclimbing: the three selected cells, hypothesis -> change ->
 re-lower -> validate. Every variant is persisted under artifacts/hillclimb/.
 
@@ -14,6 +11,7 @@ Usage: PYTHONPATH=src python scripts/hillclimb.py [A|B|C|all]
 """
 import dataclasses
 import json
+import os
 import sys
 
 import jax
@@ -146,6 +144,10 @@ def cell_C():
 
 
 if __name__ == "__main__":
+    # 512 placeholder host devices for the production meshes; read by JAX
+    # when its backend starts, so set before the first cell and never at
+    # import
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     if which in ("A", "all"):
         cell_A()

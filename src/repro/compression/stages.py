@@ -244,7 +244,7 @@ class TopkCodec(BaseCodec):
 
     def encode_batch(self, payloads, states):
         """Fused override (the QsgdCodec rule applied to sparsification):
-        every TensorPayload in the batch routes through one Pallas top-k
+        every TensorPayload in the batch routes through one top-k
         dispatch per (length, k) group (kernels/ops.topk_flat_batch);
         per-item sparse wires, info and error-feedback transitions are
         bit-identical to the per-message path. Non-tensor payloads fall

@@ -1,11 +1,9 @@
 """Top-k magnitude sparsification with error feedback (Wangni et al. 2018).
 
-The selection runs on the Pallas top-k kernel path (kernels/ops
-``topk_flat_batch``): messages sharing a (length, k) land in one fused
-kernel dispatch, and the sparse wire form — |value|-descending, ties to
-the lower index — is bit-identical to the historical per-message
-``jax.lax.top_k(|flat|)`` + gather, which remains the jitted reference
-the dispatch rule falls back to on CPU.
+The selection runs through kernels/ops ``topk_flat_batch``: messages
+sharing a (length, k) land in one stacked ``jax.lax.top_k`` dispatch, and
+the sparse wire form (|value|-descending, ties to the lower index) is
+bit-identical to the per-message ``top_k(|flat|)`` + gather.
 """
 from __future__ import annotations
 
@@ -18,23 +16,21 @@ from repro.compression.qsgd import QuantState
 from repro.kernels import ops
 
 
-def topk_compress(tree, k_frac: float, state: Optional[QuantState] = None,
-                  *, interpret=None):
+def topk_compress(tree, k_frac: float, state: Optional[QuantState] = None):
     """-> (payload dict {idx, vals, n}, new_state, unflatten)."""
     flat, unflatten = ops.flatten_pytree(tree)
     (payload,), (new_state,) = topk_compress_flat_batch(
-        [flat], [state], k_frac=k_frac, interpret=interpret)
+        [flat], [state], k_frac=k_frac)
     return payload, new_state, unflatten
 
 
-def topk_compress_flat_batch(flats, states, *, k_frac: float,
-                             interpret=None):
+def topk_compress_flat_batch(flats, states, *, k_frac: float):
     """Batched core: [flat_i], [state_i|None] -> ([payload_i],
     [new_state_i]). Same-shape messages share one fused top-k dispatch;
     per-item payloads and error-feedback transitions are bit-identical
     to ``topk_compress`` run message by message."""
     fed = [f if s is None else f + s.error for f, s in zip(flats, states)]
-    payloads = ops.topk_flat_batch(fed, k_frac=k_frac, interpret=interpret)
+    payloads = ops.topk_flat_batch(fed, k_frac=k_frac)
     new_states = [None] * len(flats)
     for i, s in enumerate(states):
         if s is None:

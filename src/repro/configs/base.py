@@ -240,7 +240,7 @@ class FLConfig:
 
     # -- the one FLConfig <-> Scenario conversion ------------------------
     def to_scenario(self, *, tier: str = "small", local_steps: int = 4,
-                    store_fail_rate: float = 0.0):
+                    reduced: bool = True, store_fail_rate: float = 0.0):
         """Lift this flat config into the declarative ``Scenario`` spec.
 
         This and its inverse, ``Scenario.fl_config()``, are THE two
@@ -249,9 +249,10 @@ class FLConfig:
         examples) routes through them, so a field added to one side must
         be added to both or the round-trip tests fail. Implemented by
         ``Scenario.from_fl_config`` (the Scenario side owns the field
-        mapping); ``tier`` / ``local_steps`` / ``store_fail_rate`` are
-        deployment knobs with no FLConfig field."""
+        mapping); ``tier`` / ``local_steps`` / ``reduced`` /
+        ``store_fail_rate`` are deployment knobs with no FLConfig field."""
         from repro.scenario import Scenario
         return Scenario.from_fl_config(self, tier=tier,
                                        local_steps=local_steps,
+                                       reduced=reduced,
                                        store_fail_rate=store_fail_rate)

@@ -33,7 +33,7 @@ def _fedavg_kernel(x_ref, w_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def fedavg_reduce(updates, weights, *, interpret: bool = True):
+def fedavg_reduce(updates, weights, *, interpret: bool = False):
     """updates: (N, T) float; weights: (N,) -> (T,) f32 weighted sum.
     T must be a multiple of COL_TILE (ops.py pads)."""
     n, t = updates.shape
@@ -59,7 +59,7 @@ def _accum_kernel(a_ref, x_ref, w_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def fedavg_accumulate(acc, x, w, *, interpret: bool = True):
+def fedavg_accumulate(acc, x, w, *, interpret: bool = False):
     """acc, x: (T,) float; w: scalar -> (T,) f32 ``acc + w * x``.
     T must be a multiple of COL_TILE (ops.py pads)."""
     t = acc.shape[0]
@@ -90,7 +90,7 @@ def _fedavg_q8_kernel(q_ref, s_ref, w_ref, o_ref, *, block: int):
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def fedavg_reduce_q8(q, scales, weights, *, block: int = 256,
-                     interpret: bool = True):
+                     interpret: bool = False):
     """q: (N, T) int8; scales: (N, T // block) f32; weights: (N,).
     Fused dequant + weighted sum -> (T,) f32."""
     n, t = q.shape

@@ -1,8 +1,10 @@
 """jit'd public wrappers over the Pallas kernels: pytree-level quantise /
 dequantise / aggregate with padding + flattening handled here.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; TPU is
-the compile target) and False on TPU.
+The kernels are written for the TPU. ``interpret=None`` resolves from the
+default backend: compiled kernels on a TPU; on the CPU, where tests and
+host-only simulator runs happen, the Pallas interpreter or the jitted
+reference (see the batched API below). Any other backend raises.
 """
 from __future__ import annotations
 
@@ -16,11 +18,17 @@ import numpy as np
 from repro.kernels import fedavg_reduce as fr
 from repro.kernels import quantize as qz
 from repro.kernels import ref as kref
-from repro.kernels import topk as tk
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """False on a TPU, True on the CPU; no other backend runs the kernels."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"the Pallas kernels target TPU; backend "
+                       f"{backend!r} has no kernel path")
 
 
 # ---------------------------------------------------------------------------
@@ -63,15 +71,13 @@ def dequantize_flat(packed, *, out_dtype=jnp.float32, interpret=None):
 # and the tiles are concatenated into one (rows, block) array, so a single
 # kernel dispatch quantises the lot — and, because quantisation is
 # row-wise, every row is bit-identical to what the per-message call would
-# have produced. Dispatch:
+# have produced. Dispatch (``interpret``):
 #
-# * TPU (``interpret`` resolves False)  — the real Pallas kernel, fused.
-# * CPU (``interpret`` resolves True)   — the jitted XLA reference
-#   (kernels/ref.py): same f32 math, parity-tested bit-exact against the
-#   interpret-mode kernel, but compiled instead of interpreted (the
-#   interpreter walks the grid in Python; it is a correctness tool, not a
-#   perf path). Pass ``interpret=True`` explicitly to force the Pallas
-#   interpreter (the parity tests do).
+# * False, or None on a TPU — the compiled Pallas kernel, fused.
+# * None on the CPU — the jitted XLA reference (kernels/ref.py): same f32
+#   math, parity-tested bit-exact against the interpret-mode kernel. The
+#   interpreter walks the grid in Python, too slow for whole-model runs.
+# * True — the Pallas interpreter (the parity tests ask for it).
 
 _jit_quantize_ref = jax.jit(kref.quantize_blocks_ref)
 _jit_dequantize_ref = jax.jit(kref.dequantize_blocks_ref)
@@ -155,29 +161,19 @@ def dequantize_flat_batch(packed_list: Sequence[dict], *,
 # batched top-k selection (the TopkCodec's fused encode path)
 # ---------------------------------------------------------------------------
 
-_jit_topk_ref = jax.jit(kref.topk_rows_ref, static_argnames=("k",))
+_jit_topk = jax.jit(kref.topk_rows_ref, static_argnames=("k",))
 
 
-def _topk_rows(rows_x, k, interpret):
-    """(B, T) -> (idx, vals) through the fastest bit-exact path (same
-    dispatch rule as ``_quantize_rows``)."""
-    if interpret is True:
-        return tk.topk_rows(rows_x, k, interpret=True)
-    if interpret is False or not _default_interpret():
-        return tk.topk_rows(rows_x, k, interpret=False)
-    return _jit_topk_ref(rows_x, k=k)
-
-
-def topk_flat_batch(flats: Sequence, *, k_frac: float = 0.05,
-                    interpret=None):
+def topk_flat_batch(flats: Sequence, *, k_frac: float = 0.05):
     """[x_i] -> [{idx, vals, n}], the top-k sparse wire form, batched.
 
     Items are grouped by (length, k) — k is ``max(1, int(size *
     k_frac))``, a per-length wire constant — and each group runs as ONE
-    stacked kernel dispatch. No padding is ever applied: padding would
-    change k and the selection set, so unequal lengths simply land in
-    different groups. Per-item results are bit-identical to the
-    per-message ``top_k(|flat|)`` + gather path (same tie rule)."""
+    stacked ``jax.lax.top_k`` dispatch on every backend. No padding is
+    ever applied: padding would change k and the selection set, so
+    unequal lengths simply land in different groups. Per-item results
+    are bit-identical to the per-message ``top_k(|flat|)`` + gather path
+    (same tie rule)."""
     if not flats:
         return []
     arrs = [np.asarray(x, np.float32).reshape(-1) for x in flats]
@@ -188,7 +184,7 @@ def topk_flat_batch(flats: Sequence, *, k_frac: float = 0.05,
     out = [None] * len(arrs)
     for (size, k), idxs in groups.items():
         stacked = jnp.asarray(np.stack([arrs[i] for i in idxs]))
-        gi, gv = _topk_rows(stacked, k, interpret)
+        gi, gv = _jit_topk(stacked, k=k)
         gi, gv = np.asarray(gi), np.asarray(gv)
         for row, i in enumerate(idxs):
             out[i] = {"idx": gi[row], "vals": gv[row], "n": size}
@@ -200,8 +196,8 @@ _jit_accumulate_ref = jax.jit(kref.fedavg_accumulate_ref)
 
 def fedavg_accumulate_flat(acc, x, w, *, interpret=None):
     """One streaming fold ``acc + w * x`` over flat (T,) vectors via the
-    fedavg_reduce accumulate kernel (CPU default: the jitted XLA
-    reference — same dispatch rule as the quantize wrappers)."""
+    fedavg_reduce accumulate kernel (same dispatch rule as the batched
+    quantize wrappers)."""
     if interpret is None and _default_interpret():
         return _jit_accumulate_ref(jnp.asarray(acc, jnp.float32),
                                    jnp.asarray(x, jnp.float32), w)

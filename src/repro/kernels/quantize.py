@@ -34,7 +34,7 @@ def _dequantize_kernel(q_ref, s_ref, x_ref, *, out_dtype):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def quantize_blocks(x, *, interpret: bool = True):
+def quantize_blocks(x, *, interpret: bool = False):
     """x: (rows, block) float -> (q int8 (rows, block), scales f32 (rows, 1)).
 
     rows must be a multiple of ROW_TILE (ops.py pads).
@@ -56,7 +56,7 @@ def quantize_blocks(x, *, interpret: bool = True):
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
 def dequantize_blocks(q, scales, *, out_dtype=jnp.float32,
-                      interpret: bool = True):
+                      interpret: bool = False):
     rows, block = q.shape
     assert rows % ROW_TILE == 0, rows
     grid = (rows // ROW_TILE,)

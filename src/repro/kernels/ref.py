@@ -55,7 +55,8 @@ def fedavg_accumulate_ref(acc, x, w):
 def topk_rows_ref(x, k: int):
     """x: (B, T) -> (idx (B, k) i32, vals (B, k) f32): the k largest-|.|
     entries per row, |value|-descending, ties broken toward the lower
-    index (jax.lax.top_k's order — and the per-message codec's)."""
+    index (jax.lax.top_k's order — and the per-message codec's). There is
+    no top-k kernel: ``ops.topk_flat_batch`` runs this on every backend."""
     vals_abs, idx = jax.lax.top_k(jnp.abs(x.astype(jnp.float32)), k)
     del vals_abs
     vals = jnp.take_along_axis(x.astype(jnp.float32), idx, axis=-1)

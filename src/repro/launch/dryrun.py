@@ -1,9 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import: jax locks the device
-# count at first init, and the production dry-run needs 512 placeholder
-# devices to build the 2x16x16 multi-pod mesh. (Tests/benches see 1 device.)
-
 """Multi-pod dry-run: ``.lower().compile()`` every (arch x shape x mesh)
 cell against the production meshes and record memory / cost / roofline.
 
@@ -19,6 +13,7 @@ Artifacts: artifacts/dryrun/<mesh>/<arch>__<shape>[__fl].json
 import argparse
 import dataclasses
 import json
+import os
 import sys
 import time
 import traceback
@@ -28,9 +23,12 @@ import jax
 from repro.configs import ARCH_ORDER, get_config
 from repro.configs.base import (MULTI_POD_MESH, SINGLE_POD_MESH, TrainConfig)
 from repro.configs.shapes import SHAPES, SHAPE_ORDER, applicability
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.launch.step_builders import bundle_for
 from repro.roofline.analysis import analyze
+
+# the chip the production meshes are made of (roofline peaks row)
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 # per-arch training knobs that make the big models fit 16 GB v5e HBM
 TRAIN_OVERRIDES = {
@@ -73,7 +71,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, fl: bool = False,
         if tuple(mesh_cfg.shape) in ((16, 16), (2, 16, 16)):
             mesh = make_production_mesh(multi_pod=multi_pod)
         else:
-            mesh = jax.make_mesh(mesh_cfg.shape, mesh_cfg.axis_names)
+            mesh = make_mesh(mesh_cfg)
     tkw = dict(TRAIN_OVERRIDES.get(arch, {}))
     if train_kw:
         tkw.update(train_kw)
@@ -96,7 +94,8 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, fl: bool = False,
         pod_size = 256 if multi_pod else 0
         rl = analyze(compiled, arch=arch, shape=shape, kind=kind,
                      mesh_name=mesh_name, chips=mesh.devices.size,
-                     pod_size=pod_size, cfg=cfg)
+                     pod_size=pod_size, cfg=cfg,
+                     device_kind=TARGET_DEVICE_KIND)
         if fl:
             # an FL round performs local_steps optimizer steps per call
             rl.model_flops *= fl_local_steps
@@ -190,4 +189,8 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # the 2x16x16 production mesh needs 512 placeholder host devices; JAX
+    # reads the flag when its backend first starts, so it is set here and
+    # never by importing this module
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     sys.exit(main())

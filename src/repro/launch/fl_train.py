@@ -17,6 +17,10 @@ overrides layered onto the default scenario):
     PYTHONPATH=src python -m repro.launch.fl_train --backend grpc+s3 \
         --environment geo_distributed --rounds 3 --tier small
 
+Live runs deploy a reduced ResNet by default so CPU rounds take seconds;
+``--no-reduced`` deploys the tier's model at its published configuration
+(the small tier is ResNet56 on 32-px, 203-class silos).
+
 ``--environment`` accepts the graph presets (star / ring / multi_hub) as
 well as the legacy trio. ``--mode fedbuff|semisync|hier`` switches to the
 event-driven runtime (fl/scheduler.py): clients run independently and
@@ -26,6 +30,9 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import json
+import math
+import time
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +45,7 @@ from repro.core.backends import BACKEND_NAMES
 from repro.data import make_silo_datasets
 from repro.fl import FLClient, FLServer, make_strategy
 from repro.fl.fault import FaultPlan, apply_stragglers, make_availability
+from repro.launch.compile_cache import enable_compile_cache
 from repro.scenario import (TOPOLOGY_PRESETS, Scenario, ScenarioError,
                             build_runtime, with_overrides)
 
@@ -46,7 +54,9 @@ def build_deployment(fl_cfg: FLConfig, *, tier: str = "small",
                      reduced: bool = True, local_steps: int = 4,
                      fail_rate: float = 0.0, scenario: Scenario = None):
     """FLConfig/Scenario -> live deployment, through the scenario runtime
-    (the same path ``--scenario`` files take).
+    (the same path ``--scenario`` files take). With a scenario, its
+    ``fleet`` (tier, local steps, reduced) decides; the keyword arguments
+    describe the fleet only when no scenario is given.
 
     Passing *both* ``fl_cfg`` and ``scenario`` is only legal when they
     agree: the scenario's flat projection (``Scenario.fl_config()``)
@@ -67,34 +77,40 @@ def build_deployment(fl_cfg: FLConfig, *, tier: str = "small",
         sc = scenario
     else:
         sc = fl_cfg.to_scenario(tier=tier, local_steps=local_steps,
-                                store_fail_rate=fail_rate)
+                                reduced=reduced, store_fail_rate=fail_rate)
+    tier, local_steps = sc.fleet.tier, sc.fleet.local_steps
     rt = build_runtime(sc)
     env, store = rt.env, rt.store
 
-    if reduced:
+    if sc.fleet.reduced:
         # reduced same-family model so CPU rounds take seconds
         from repro.models.vision import ResNet, ResNetConfig
         model = ResNet(ResNetConfig(blocks_per_stage=2, num_classes=8,
                                     image_size=16))
     else:
         model, _ = build_tier_model(tier)
+    if not hasattr(model.cfg, "image_size"):
+        raise ValueError(f"live training builds image silos; tier "
+                         f"{tier!r} deploys {type(model).__name__}")
     rng = jax.random.key(fl_cfg.seed)
     params = model.init(rng)
 
     silos = make_silo_datasets(fl_cfg.num_clients, kind="image",
-                               examples_per_silo=64, num_classes=8,
-                               image_size=16, seed=fl_cfg.seed)
+                               examples_per_silo=64,
+                               num_classes=model.cfg.num_classes,
+                               image_size=model.cfg.image_size,
+                               seed=fl_cfg.seed)
 
-    def make_train_fn():
-        @jax.jit
-        def train_fn(params, batch):
-            def loss_fn(p):
-                loss, _ = model.loss(p, batch)
-                return loss
-            loss, grads = jax.value_and_grad(loss_fn)(params)
-            params2 = jax.tree.map(lambda p, g: p - 0.05 * g, params, grads)
-            return params2, loss
-        return train_fn
+    # one compiled step for the whole deployment: every client trains the
+    # same model on same-shaped batches
+    @jax.jit
+    def train_fn(params, batch):
+        def loss_fn(p):
+            loss, _ = model.loss(p, batch)
+            return loss
+        loss, grads = jax.value_and_grad(loss_fn)(params)
+        params2 = jax.tree.map(lambda p, g: p - 0.05 * g, params, grads)
+        return params2, loss
 
     # event-driven modes charge the tier-calibrated training time instead
     # of measured wall seconds ("live compute, simulated clock"): jit
@@ -120,7 +136,7 @@ def build_deployment(fl_cfg: FLConfig, *, tier: str = "small",
     for i, host in enumerate(env.clients):
         cb = rt.make_backend(host.host_id, compression=client_compression)
         clients.append(FLClient(host.host_id, cb, dataset=silos[i],
-                                train_fn=make_train_fn(), batch_size=16,
+                                train_fn=train_fn, batch_size=16,
                                 sim_train_s=sim_train,
                                 seed=fl_cfg.seed + i))
     server_backend = rt.make_backend("server",
@@ -185,14 +201,16 @@ def run_event_driven(fl_cfg: FLConfig, server: FLServer, params, store,
         fl_cfg.availability_trace,
         [c.client_id for c in server.clients],
         horizon_s=scenario.faults.trace_horizon_s, seed=fl_cfg.seed)
+    t0 = time.perf_counter()
     report, sched = server.run_async(global_payload, strategy,
                                      availability=availability,
                                      cohort_k=fl_cfg.cohort_k,
                                      cohort_seed=fl_cfg.seed,
                                      streaming_hub=fl_cfg.streaming_hub,
                                      max_aggregations=fl_cfg.rounds)
+    wall = time.perf_counter() - t0
     print(f"[fl:{report.mode}] backend={report.backend} "
-          f"sim_time={report.sim_time:.2f}s "
+          f"wall={wall:.3f}s sim_time={report.sim_time:.2f}s "
           f"aggregations={report.n_aggregations} "
           f"client_updates={report.n_client_updates} "
           f"(effective {report.effective_updates:.2f}, "
@@ -214,6 +232,17 @@ def run_event_driven(fl_cfg: FLConfig, server: FLServer, params, store,
     print(f"[fl:{report.mode}] throughput={report.aggregations_per_hour:.1f} "
           f"agg/h, {report.client_updates_per_hour:.1f} updates/h "
           f"({'improving' if ok else 'check'})  s3_stats={store.stats}")
+    print(f"[fl:{report.mode}] losses: {json.dumps(losses)}")
+    return _finite_or_fail(losses)
+
+
+def _finite_or_fail(losses) -> int:
+    """Exit code of a live run: 1, with a message, if any loss is not
+    finite (a diverged or broken run must not exit 0)."""
+    bad = [l for l in losses if l is not None and not math.isfinite(l)]
+    if bad:
+        print(f"[fl] ERROR: non-finite loss {bad[0]!r}; failing the run")
+        return 1
     return 0
 
 
@@ -258,6 +287,11 @@ def _parser() -> argparse.ArgumentParser:
     ap.add_argument("--drop-rate", type=float, default=0.0,
                     help="sync-mode per-round client drop rate (FaultPlan)")
     ap.add_argument("--tier", default=None)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=None,
+                    help="live model: the reduced 16-px ResNet (default) "
+                         "or, with --no-reduced, the tier's model at its "
+                         "published configuration")
     ap.add_argument("--mode", default=None,
                     choices=["sync", "fedbuff", "semisync", "hier",
                              "vertical"])
@@ -333,6 +367,7 @@ def resolve_scenario(args, ap: argparse.ArgumentParser) -> Scenario:
             "topology.num_clients": args.clients,
             "fleet.tier": args.tier,
             "fleet.local_steps": args.local_steps,
+            "fleet.reduced": args.reduced,
             "strategy.mode": args.mode,
             "strategy.rounds": args.rounds,
             "strategy.buffer_k": args.buffer_k,
@@ -372,6 +407,7 @@ def resolve_scenario(args, ap: argparse.ArgumentParser) -> Scenario:
 def main(argv=None):
     ap = _parser()
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.sweep:
         # a sweep file is a whole grid of scenarios, not one training
         # run: expand + execute through the engine's resumable run store
@@ -425,10 +461,9 @@ def main(argv=None):
     fl_cfg = sc.fl_config()
     print(f"[fl] scenario '{sc.name}': topology={sc.topology.kind} "
           f"x{sc.topology.num_clients} backend={sc.channel.backend} "
-          f"mode={sc.strategy.mode} tier={sc.fleet.tier}")
-    server, params, env, store = build_deployment(
-        fl_cfg, tier=sc.fleet.tier, local_steps=sc.fleet.local_steps,
-        scenario=sc)
+          f"mode={sc.strategy.mode} tier={sc.fleet.tier} "
+          f"reduced={sc.fleet.reduced}")
+    server, params, env, store = build_deployment(fl_cfg, scenario=sc)
     if sc.strategy.mode != "sync":
         return run_event_driven(fl_cfg, server, params, store, sc)
     fault = FaultPlan(drop_rate=args.drop_rate, seed=1)
@@ -438,11 +473,14 @@ def main(argv=None):
         dropped, stragglers = fault.for_round(r, [c.client_id for c in
                                                   server.clients])
         apply_stragglers(server.clients, stragglers, fault.straggler_factor)
+        t0 = time.perf_counter()
         report = server.run_round(TensorPayload(params), dropped=dropped)
         if server.global_params is not None:
             params = server.global_params
+        jax.block_until_ready(params)
+        wall = time.perf_counter() - t0
         losses.append(report.losses)
-        print(f"[fl] round {r}: t={report.round_time:8.2f}s sim "
+        print(f"[fl] round {r}: wall={wall:.3f}s t={report.round_time:8.2f}s sim "
               f"loss={report.losses if report.losses else float('nan'):.4f} "
               f"participants={report.n_participants} "
               f"server_mem={report.peak_server_memory / 2**20:.1f}MB "
@@ -455,9 +493,9 @@ def main(argv=None):
               f"wait={cl['waiting']:.2f}")
     ok = losses[-1] is not None and losses[0] is not None and \
         losses[-1] < losses[0] + 1e-6
-    print(f"[fl] losses: {['%.3f' % l if l else 'n/a' for l in losses]} "
+    print(f"[fl] losses: {json.dumps(losses)} "
           f"({'improving' if ok else 'check'})  s3_stats={store.stats}")
-    return 0
+    return _finite_or_fail(losses)
 
 
 if __name__ == "__main__":

@@ -1,8 +1,14 @@
 """Mesh construction. Functions, not constants: importing this module never
-touches jax device state."""
+touches jax device state.
+
+Every mesh in the repository is built here with ``Auto`` axes:
+``Sharder`` places activations with ``with_sharding_constraint``, which
+accepts only Auto axes, while ``jax.make_mesh`` defaults to Explicit ones.
+"""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.configs.base import (MULTI_POD_MESH, SINGLE_POD_MESH, SMOKE_MESH,
                                 MeshConfig)
@@ -13,16 +19,23 @@ def make_production_mesh(*, multi_pod: bool = False):
     2x16x16 (two pods, 512 chips, 'pod' axis over DCN)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
-def make_mesh(cfg: MeshConfig):
-    return jax.make_mesh(cfg.shape, cfg.axis_names)
+def auto_mesh(shape, axis_names, *, devices=None):
+    """``jax.make_mesh`` with every axis Auto (see the module docstring)."""
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
+
+
+def make_mesh(cfg: MeshConfig, *, devices=None):
+    return auto_mesh(cfg.shape, cfg.axis_names, devices=devices)
 
 
 def make_smoke_mesh():
     """1x1 mesh over the single local device (smoke tests / examples)."""
-    return jax.make_mesh(SMOKE_MESH.shape, SMOKE_MESH.axis_names)
+    return make_mesh(SMOKE_MESH)
 
 
 def mesh_config_for(mesh) -> MeshConfig:

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_ORDER, smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_smoke_mesh
 
 
@@ -26,6 +27,7 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch)
     if not cfg.causal:

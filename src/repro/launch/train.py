@@ -20,6 +20,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import ARCH_ORDER, get_config, smoke_config
 from repro.configs.base import SMOKE_MESH, ShapeConfig, TrainConfig
 from repro.data import lm_batch_iterator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_smoke_mesh
 from repro.launch.step_builders import make_train_step
 from repro.models.layers import abstract_init
@@ -37,6 +38,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--lr", type=float, default=1e-3)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     shape = ShapeConfig(name="cli", seq_len=args.seq,

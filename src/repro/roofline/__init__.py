@@ -1,6 +1,6 @@
-from repro.roofline.analysis import (DCN_BW, HBM_BW, ICI_BW, PEAK_FLOPS,
-                                     Roofline, analyze, model_flops_for)
+from repro.roofline.analysis import (DCN_BW, PEAKS, ChipPeaks, Roofline,
+                                     analyze, model_flops_for, peaks_for)
 from repro.roofline.hlo_cost import Cost, entry_cost
 
 __all__ = ["analyze", "Roofline", "entry_cost", "Cost", "model_flops_for",
-           "PEAK_FLOPS", "HBM_BW", "ICI_BW", "DCN_BW"]
+           "PEAKS", "ChipPeaks", "peaks_for", "DCN_BW"]

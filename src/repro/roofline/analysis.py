@@ -1,4 +1,5 @@
-"""3-term roofline from a compiled dry-run artifact (TPU v5e targets).
+"""3-term roofline from a compiled dry-run artifact, against the peaks
+of a named device kind (``PEAKS``).
 
 compute term    = HLO_FLOPs / (chips * peak_FLOP/s)
 memory term     = HLO_bytes / (chips * HBM_bw)
@@ -26,11 +27,31 @@ from typing import Optional
 
 from repro.roofline.hlo_cost import Cost, entry_cost
 
-# v5e hardware constants (per chip)
-PEAK_FLOPS = 197e12  # bf16
-HBM_BW = 819e9  # bytes/s
-ICI_BW = 50e9  # bytes/s per link (brief's constant)
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks."""
+    flops: float  # bf16 FLOP/s
+    hbm_bw: float  # bytes/s
+    ici_bw: float  # bytes/s per link
+
+
+# Keyed by ``jax.Device.device_kind``. TPU v5e: Google Cloud documentation,
+# "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM); the ICI figure is the
+# per-link share used for the collective term.
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
 DCN_BW = 6.25e9  # bytes/s per host across pods
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """The table row for ``device_kind``; a kind not in it is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 @dataclasses.dataclass
@@ -40,6 +61,7 @@ class Roofline:
     kind: str
     mesh: str
     chips: int
+    device_kind: str
     hlo_flops: float
     hlo_bytes: float  # buffer-inventory traffic (args + outputs + 2*temps)
     hlo_walk_bytes: float  # raw HLO operand-byte walk (diagnostic)
@@ -54,9 +76,10 @@ class Roofline:
     t_dcn: float = 0.0
 
     def finalize(self):
-        self.t_compute = self.hlo_flops / PEAK_FLOPS
-        self.t_memory = self.hlo_bytes / HBM_BW
-        self.t_collective = self.coll_ici_bytes / ICI_BW
+        peaks = peaks_for(self.device_kind)
+        self.t_compute = self.hlo_flops / peaks.flops
+        self.t_memory = self.hlo_bytes / peaks.hbm_bw
+        self.t_collective = self.coll_ici_bytes / peaks.ici_bw
         self.t_dcn = self.coll_dcn_bytes / DCN_BW
         return self
 
@@ -83,7 +106,7 @@ class Roofline:
         """Fraction of the compute roofline achieved if the program ran at
         its bound: (useful flops / peak) / bound_time."""
         per_chip_model = self.model_flops / self.chips
-        ideal = per_chip_model / PEAK_FLOPS
+        ideal = per_chip_model / peaks_for(self.device_kind).flops
         return ideal / max(self.bound_time, 1e-30)
 
     def to_dict(self):
@@ -106,15 +129,15 @@ def model_flops_for(cfg, shape) -> float:
 
 
 def analyze(compiled, *, arch: str, shape, kind: str, mesh_name: str,
-            chips: int, pod_size: int, cfg) -> Roofline:
+            chips: int, pod_size: int, cfg, device_kind: str) -> Roofline:
     cost = entry_cost(compiled.as_text(), pod_size=pod_size)
     mem = compiled.memory_analysis()
     traffic = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                + 2 * mem.temp_size_in_bytes)
     rl = Roofline(
         arch=arch, shape=shape.name, kind=kind, mesh=mesh_name, chips=chips,
-        hlo_flops=cost.flops, hlo_bytes=float(traffic),
-        hlo_walk_bytes=cost.hbm_bytes,
+        device_kind=device_kind, hlo_flops=cost.flops,
+        hlo_bytes=float(traffic), hlo_walk_bytes=cost.hbm_bytes,
         coll_ici_bytes=cost.coll_ici_bytes,
         coll_dcn_bytes=cost.coll_dcn_bytes, coll_by_op=cost.coll_by_op,
         model_flops=model_flops_for(cfg, shape))

@@ -402,13 +402,14 @@ def computation_cost(name: str, comps, pod_size: int,
     return total
 
 
-# TPU-class machine balance (peak flops / HBM bandwidth), flops per byte:
-# ~197 Tf/s over ~0.82 TB/s ≈ 240. A kernel whose arithmetic intensity
-# sits far below this is bandwidth-bound — more compute cannot speed it
-# up, only fewer bytes can (which is what fusing a batch of encodes into
-# one dispatch buys: the fixed dispatch/launch cost amortises and the
-# rows stream once).
-MACHINE_BALANCE_FLOPS_PER_BYTE = 240.0
+def machine_balance(device_kind: str) -> float:
+    """Peak FLOP/s over HBM bytes/s of ``device_kind``, in flops per byte.
+    A computation whose arithmetic intensity sits below it is
+    bandwidth-bound: more compute cannot speed it up, only fewer bytes
+    can."""
+    from repro.roofline.analysis import peaks_for
+    p = peaks_for(device_kind)
+    return p.flops / p.hbm_bw
 
 
 def arithmetic_intensity(cost: Cost) -> float:
@@ -418,14 +419,12 @@ def arithmetic_intensity(cost: Cost) -> float:
     return cost.flops / cost.hbm_bytes
 
 
-def is_bandwidth_bound(cost: Cost, *, balance: float =
-                       MACHINE_BALANCE_FLOPS_PER_BYTE) -> bool:
+def is_bandwidth_bound(cost: Cost, *, device_kind: str) -> bool:
     """True when the computation's intensity sits below the machine
-    balance point — the roofline says HBM bandwidth, not compute, limits
-    it. The batched-codec CI assertion: the fused quantize stage must
-    stay bandwidth-bound (it streams rows; if intensity ever climbs the
-    fusion regressed into recomputation)."""
-    return arithmetic_intensity(cost) < balance
+    balance of ``device_kind``. The batched-codec CI assertion: the fused
+    quantize stage must stay bandwidth-bound (it streams rows; if
+    intensity ever climbs the fusion regressed into recomputation)."""
+    return arithmetic_intensity(cost) < machine_balance(device_kind)
 
 
 def entry_cost(text: str, pod_size: int = 0) -> Cost:

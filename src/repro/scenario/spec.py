@@ -13,7 +13,7 @@ into five frozen sub-specs:
   environments bit-for-bit (regression-tested); ``star`` / ``ring`` /
   ``multi_hub`` are graph-native topologies in the Marfoq & Neglia
   throughput-optimal-topology line (benchmarks/fig9_topology_wan.py).
-* ``FleetSpec``    — who trains: tier, local steps.
+* ``FleetSpec``    — who trains: tier, local steps, reduced or full model.
 * ``ChannelSpec``  — what the wire stack looks like: backend, payload
   codec, wire codec, chunking.
 * ``FaultSpec``    — what goes wrong: link loss, NACK timing, store
@@ -345,6 +345,10 @@ class FleetSpec:
     """Who trains: the model tier + local work per dispatch."""
     tier: str = "small"
     local_steps: int = 4
+    # live runs: True deploys a 16-px, 2-blocks-per-stage ResNet on 8-class
+    # silos so CPU rounds take seconds; False deploys the tier's model at
+    # its published configuration on silos shaped by that model's config
+    reduced: bool = True
     # cohort sampling (the cross-device regime at fleet scale): each
     # aggregation round draws a seeded K-of-N client sample; 0 (or
     # K >= N) keeps the whole fleet in play, bit-for-bit today's runs
@@ -588,7 +592,7 @@ class Scenario:
 
     @classmethod
     def from_fl_config(cls, cfg, *, tier: str = "small",
-                       local_steps: int = 4,
+                       local_steps: int = 4, reduced: bool = True,
                        store_fail_rate: float = 0.0) -> "Scenario":
         """The inverse bridge: lift a flat FLConfig into the declarative
         spec (legacy entry points — tests, examples — resolve through the
@@ -600,6 +604,7 @@ class Scenario:
                                   relay_depth=getattr(cfg, "relay_depth",
                                                       1)),
             fleet=FleetSpec(tier=tier, local_steps=local_steps,
+                            reduced=reduced,
                             cohort_k=getattr(cfg, "cohort_k", 0)),
             channel=ChannelSpec(backend=cfg.backend,
                                 compression=cfg.compression,

@@ -28,6 +28,18 @@ from repro.sweep.result import CellResult
 from repro.sweep.spec import Cell, Sweep
 
 
+def refuse_pool_on_tpu(workers: int) -> None:
+    """A worker pool starts one JAX process per worker, and a TPU chip
+    belongs to one process at a time: on a TPU only the parent may run
+    cells, so ``workers > 1`` is refused there."""
+    if workers > 1:
+        import jax
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                f"workers={workers}: a process pool cannot share the TPU "
+                f"(one process per chip); run with workers <= 1")
+
+
 def fingerprint(study: str, version: int, cell: Cell) -> str:
     """Content address of one cell: the study identity + the *complete*
     cell spec (frozen scenario + params). Bumping ``Study.version``
@@ -151,6 +163,7 @@ class Engine:
         store writer, so the store file is bit-for-bit identical to a
         serial run of the same grid — cells must be (and the studies
         are) deterministic, which ``--workers`` therefore preserves."""
+        refuse_pool_on_tpu(workers)
         store = RunStore(self.store_path(study.name))
         stats = StudyRunStats(n_cells=len(cells))
         fps = [fingerprint(study.name, study.version, cell)

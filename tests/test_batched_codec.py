@@ -183,6 +183,6 @@ def test_fused_quantize_stage_is_bandwidth_bound():
     cost = entry_cost(c.as_text())
     ai = arithmetic_intensity(cost)
     assert np.isfinite(ai)
-    assert is_bandwidth_bound(cost), (
+    assert is_bandwidth_bound(cost, device_kind="TPU v5 lite"), (
         f"quantize stage should sit under the machine balance, got "
         f"intensity {ai:.1f} flops/byte")

@@ -1,7 +1,6 @@
 """Checkpoint/restart: integrity, keep-k GC, async writes, reshard restore."""
 import os
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -57,7 +56,8 @@ def test_restore_with_new_sharding(tmp_path):
     from jax.sharding import NamedSharding, PartitionSpec as P
     d = str(tmp_path)
     save_checkpoint(d, 1, _tree(3.0))
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((1,), ("data",))
     sh = {"layer": {"w": NamedSharding(mesh, P("data")),
                     "b": NamedSharding(mesh, P())},
           "step_scale": NamedSharding(mesh, P())}

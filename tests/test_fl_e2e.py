@@ -97,3 +97,18 @@ def test_report_states_cover_paper_fig5():
               "training"):
         assert k in cl and cl[k] >= 0
     assert cl["training"] > 0
+
+
+def test_full_model_deployment_shapes_silos_from_its_config():
+    """--no-reduced deploys the tier's published model; the silos follow
+    its config and every client shares one compiled step."""
+    server, params, _, _ = build_deployment(FLConfig(num_clients=3),
+                                            reduced=False)
+    cfg = server.model.cfg
+    assert (cfg.name, cfg.blocks_per_stage) == ("resnet56", 9)
+    for c in server.clients:
+        assert c.dataset.features.shape[1:] == (cfg.image_size,
+                                                cfg.image_size, 3)
+        assert c.dataset.num_classes == cfg.num_classes == 203
+    assert len({id(c.train_fn) for c in server.clients}) == 1
+    assert params["head"]["w"].shape == (64, 203)

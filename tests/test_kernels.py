@@ -5,7 +5,8 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.fedavg_reduce import COL_TILE, fedavg_reduce, fedavg_reduce_q8
+from repro.kernels.fedavg_reduce import (COL_TILE, fedavg_accumulate,
+                                         fedavg_reduce, fedavg_reduce_q8)
 from repro.kernels.quantize import ROW_TILE, dequantize_blocks, quantize_blocks
 
 
@@ -90,3 +91,29 @@ def test_flatten_roundtrip_mixed_dtypes(rng):
     rec = unflatten(flat)
     assert rec["b"].dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(rec["w"]), np.asarray(tree["w"]))
+
+
+@pytest.mark.parametrize("fn", [quantize_blocks, dequantize_blocks,
+                                fedavg_reduce, fedavg_reduce_q8,
+                                fedavg_accumulate])
+def test_kernel_entry_points_default_to_compiled(fn):
+    import inspect
+    assert inspect.signature(fn).parameters["interpret"].default is False
+
+
+def test_ops_refuse_backends_without_a_kernel_path(monkeypatch, rng):
+    x = rng.normal(size=300).astype(np.float32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="target TPU"):
+        ops.quantize_flat_batch([x])
+    with pytest.raises(RuntimeError, match="target TPU"):
+        ops.fedavg_aggregate([{"x": jnp.asarray(x)}], [1.0])
+
+
+def test_topk_batch_is_lax_top_k_on_every_backend(rng):
+    x = rng.normal(size=(3, 200)).astype(np.float32)
+    out = ops.topk_flat_batch(list(x), k_frac=0.1)
+    for row, o in zip(x, out):
+        want = np.argsort(-np.abs(row), kind="stable")[:20]
+        np.testing.assert_array_equal(o["idx"], want)
+        np.testing.assert_array_equal(o["vals"], row[want])

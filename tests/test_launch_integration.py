@@ -81,18 +81,12 @@ def test_trainer_checkpoint_restart(tmp_path):
 def test_fl_round_bundle_on_pod_mesh():
     """The paper-technique step lowers when a pod axis exists (uses the
     2-device CPU mesh via axis sizes (2,1,1))."""
-    import dataclasses
     from repro.configs.base import MeshConfig
-    if jax.device_count() < 2:
-        mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
-        mcfg = MeshConfig(shape=(1, 1, 1),
-                          axis_names=("pod", "data", "model"))
-        n_pods = 1
-    else:
-        mesh = jax.make_mesh((2, 1, 1), ("pod", "data", "model"))
-        mcfg = MeshConfig(shape=(2, 1, 1),
-                          axis_names=("pod", "data", "model"))
-        n_pods = 2
+    from repro.launch.mesh import make_mesh
+    n_pods = 1 if jax.device_count() < 2 else 2
+    mcfg = MeshConfig(shape=(n_pods, 1, 1),
+                      axis_names=("pod", "data", "model"))
+    mesh = make_mesh(mcfg)
     cfg = smoke_config("qwen3-8b")
     shape = ShapeConfig(name="t", seq_len=16, global_batch=2 * n_pods,
                         kind="train")

@@ -155,3 +155,21 @@ def test_workers_flag_plumbed_through_registry():
     assert entries["fig8"].accepts_workers
     # legacy non-sweep modules must not be handed a workers kwarg
     assert not entries["kernels"].accepts_workers
+
+
+# ---------------------------------------------------------------------------
+# one process per chip: no worker pool on a TPU
+# ---------------------------------------------------------------------------
+
+def test_workers_refused_when_backend_is_tpu(tmp_path, monkeypatch):
+    import jax
+    from benchmarks.fig4a_p2p_latency import STUDY
+    from repro.sweep.engine import refuse_pool_on_tpu
+    cells = [c for sw in STUDY.sweeps(True) for c in sw.expand()][:2]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="one process per chip"):
+        Engine(str(tmp_path)).run_cells(STUDY, cells, verbose=False,
+                                        workers=2)
+    refuse_pool_on_tpu(1)  # the parent alone may run cells
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    refuse_pool_on_tpu(4)

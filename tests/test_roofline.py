@@ -90,3 +90,13 @@ def test_dus_counts_slice_not_buffer():
     buf_bytes = 4096 * 256 * 4
     # 64 iterations touching a 4x256 slice each must NOT count 64 full buffers
     assert cost.hbm_bytes < 10 * buf_bytes
+
+
+def test_peaks_keyed_by_device_kind():
+    from repro.roofline import peaks_for
+    from repro.roofline.hlo_cost import machine_balance
+    v5e = peaks_for("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    assert machine_balance("TPU v5 lite") == pytest.approx(240.5, abs=0.1)
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks_for("cpu")

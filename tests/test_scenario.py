@@ -413,3 +413,28 @@ def test_runtime_builds_fault_model_from_spec():
     assert fm is not None and fm.chunk_loss_rate == 0.1
     assert fm.max_retries == 7 and fm.nack_rtts == 2.0 and fm.seed == 3
     assert build_runtime(Scenario(name="c")).fabric.fault_model is None
+
+
+def test_fl_train_reduced_flag_overrides_fleet(tmp_path):
+    sc = _resolve(tmp_path, {"name": "t"}, [])
+    assert sc.fleet.reduced is True  # CPU default: the reduced ResNet
+    sc = _resolve(tmp_path, {"name": "t"}, ["--no-reduced"])
+    assert sc.fleet.reduced is False
+    spec = {"name": "t", "fleet": {"reduced": False}}
+    assert _resolve(tmp_path, spec, []).fleet.reduced is False
+    assert _resolve(tmp_path, spec, ["--reduced"]).fleet.reduced is True
+    back = Scenario.from_dict(json.loads(json.dumps(
+        _resolve(tmp_path, spec, []).to_dict())))
+    assert back.fleet.reduced is False
+
+
+@pytest.mark.parametrize("losses,rc", [
+    ([2.0, 1.5], 0),
+    ([2.0, 2.5], 0),  # not improving: noisy, still a finished run
+    ([2.0, None], 0),  # a round without live clients
+    ([2.0, float("nan")], 1),
+    ([float("inf")], 1),
+])
+def test_fl_train_fails_on_non_finite_loss(losses, rc):
+    from repro.launch.fl_train import _finite_or_fail
+    assert _finite_or_fail(losses) == rc
