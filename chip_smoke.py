@@ -83,31 +83,6 @@ def require_tpu(n_chips: int):
     return devs
 
 
-class CompileMeter:
-    """Backend compile seconds and persistent-cache hits, read from JAX's
-    monitoring events."""
-
-    def __init__(self):
-        self.compile_s = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def take(self):
-        """-> (compile seconds, cache hits) since the last call."""
-        out = (self.compile_s, self.cache_hits)
-        self.compile_s, self.cache_hits = 0.0, 0
-        return out
-
-
 # ---------------------------------------------------------------------------
 # one chip: kernels
 # ---------------------------------------------------------------------------
@@ -227,22 +202,34 @@ class _Tee(io.TextIOBase):
 
 
 _LOSSES = re.compile(r"^\[fl(?::\w+)?\] losses: (\[.*?\])", re.M)
-_SYNC_WALL = re.compile(r"^\[fl\] round \d+: wall=([0-9.]+)s", re.M)
-_ASYNC_WALL = re.compile(
-    r"^\[fl:\w+\] backend=\S+ wall=([0-9.]+)s .*?aggregations=(\d+)", re.M)
 
 
-def run_fl(name: str, argv, meter: CompileMeter, *, sync: bool):
+def round_walls(rec, *, sync: bool):
+    """Host seconds of each sync round (its ``round`` span), or of each
+    merge: from the run's start (``sched.run``) or the previous merge to
+    the end of its ``sched.aggregate`` span."""
+    if sync:
+        return [s.seconds for s in rec.named("round")]
+    (run,) = rec.named("sched.run")
+    ends = [s.end for s in rec.named("sched.aggregate")]
+    return [b - a for a, b in zip([run.start] + ends, ends)]
+
+
+def run_fl(name: str, argv, *, sync: bool):
     """``fl_train.main(argv)`` in this process; asserts exit 0, finite
     losses and, for sync, a last-round loss no higher than the first."""
+    from repro import obs
     from repro.launch import fl_train
     buf = io.StringIO()
-    meter.take()
+    rec = obs.enable()
     t0 = time.perf_counter()
-    with contextlib.redirect_stdout(_Tee(sys.stdout, buf)):
-        rc = fl_train.main(list(argv))
+    try:
+        with contextlib.redirect_stdout(_Tee(sys.stdout, buf)):
+            rc = fl_train.main(list(argv))
+    finally:
+        obs.disable()
     wall = time.perf_counter() - t0
-    compile_s, hits = meter.take()
+    compile_s, hits = rec.total("jax.compile_s"), rec.total("jax.cache_hits")
     out = buf.getvalue()
     if rc != 0:
         raise RuntimeError(f"fl_train ({name}) exited {rc}")
@@ -253,24 +240,18 @@ def run_fl(name: str, argv, meter: CompileMeter, *, sync: bool):
     if len(losses) != 3 or not all(
             l is not None and math.isfinite(l) for l in losses):
         raise AssertionError(f"{name}: want 3 finite losses, got {losses}")
-    if sync:
-        round_wall = [float(v) for v in _SYNC_WALL.findall(out)]
-        if not losses[-1] <= losses[0]:
-            raise AssertionError(f"{name}: last-round loss {losses[-1]} > "
-                                 f"first {losses[0]}")
-    else:
-        am = _ASYNC_WALL.search(out)
-        round_wall = [float(am.group(1)) / int(am.group(2))]
+    if sync and not losses[-1] <= losses[0]:
+        raise AssertionError(f"{name}: last-round loss {losses[-1]} > "
+                             f"first {losses[0]}")
     print(f"[smoke] {name}: compile_s={compile_s!r} "
-          f"persistent_cache_hits={hits} run_wall_s={wall!r} "
-          f"wall_s_per_round={round_wall} losses={losses}")
+          f"persistent_cache_hits={hits:g} run_wall_s={wall!r} "
+          f"wall_s_per_round={round_walls(rec, sync=sync)} losses={losses}")
 
 
 def one_chip():
     """-> names of the phases that failed."""
-    meter = CompileMeter()
     phases = [("kernels", check_kernels)] + [
-        (f"fl_train {name}", functools.partial(run_fl, name, argv, meter,
+        (f"fl_train {name}", functools.partial(run_fl, name, argv,
                                                sync=name == "sync"))
         for name, argv in FL_RUNS.items()]
     return run_phases(phases)

@@ -104,6 +104,7 @@ class CommBackend:
                                     wire_codec=wire_codec,
                                     chunk_bytes=int(chunk_mb * MB),
                                     error_feedback=error_feedback)
+        self.channel.host = host_id
         self._ser_busy_until = 0.0  # sender serializer busy-line (isend)
 
     def _encode(self, msg: FLMessage) -> Encoded:

@@ -36,12 +36,19 @@ it*, never by its own configuration (AUTO routing, mixed fleets, and the
 object store all stay coherent). With the default ``[SerializeStage]``
 stack every byte and every simulated second is identical to the
 pre-stack code — regression-tested.
+
+Host time: ``encode`` and ``decode`` are ``repro.obs`` spans
+(``wire.encode`` / ``wire.decode``, attribute ``side`` = the owning
+host) around one span per stage, ``wire.<stage name>``; the fused codec
+dispatch of ``encode_many`` / ``decode_batch`` is a ``wire.compress``
+span.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import List, Optional, Tuple
 
+from repro import obs
 from repro.core.serialization import (BaseSerializer, SERIALIZERS, WireData,
                                       decode_wire)
 
@@ -186,6 +193,7 @@ class Channel:
         self.compress_stage: Optional[CompressStage] = next(
             (s for s in self._order if isinstance(s, CompressStage)
              and not isinstance(s, WireCompressStage)), None)
+        self.host = ""  # the owning backend's host id (span attribute)
 
     # ------------------------------------------------------------------
     def signature(self) -> str:
@@ -202,54 +210,60 @@ class Channel:
         ``_pre`` is a precomputed ``(payload', info)`` for the payload
         compress stage (``encode_many``'s fused dispatch); the charges,
         provenance and wire are identical to computing it here."""
-        charges: List[Tuple[str, float, int]] = []
-        infos: List[dict] = []
-        wire: Optional[WireData] = None
-        chunks = None
-        for stage in self._order:
-            if isinstance(stage, WireCompressStage):
-                out, info = stage.compress(wire)
-                if info is not None:
-                    charges.append((stage.name,
-                                    stage.codec.enc_time(info["orig_nbytes"]),
-                                    out.nbytes))
-                    infos.append(info)
-                    wire = out
-            elif isinstance(stage, CompressStage):
-                orig_nbytes = payload.nbytes
-                if _pre is not None:
-                    payload, info = _pre
-                else:
-                    payload, info = stage.compress(payload, peer)
-                if info is not None:
-                    charges.append((stage.name,
-                                    stage.codec.enc_time(orig_nbytes),
-                                    payload.nbytes))
-                    infos.append(info)
-            elif isinstance(stage, SerializeStage):
-                wire = stage.serializer.serialize(payload)
-                charges.append((stage.name,
-                                stage.serializer.ser_time(wire.nbytes), 0))
-                infos.append({"stage": "serialize", "codec": wire.codec})
-            elif isinstance(stage, ChunkStage):
-                sizes = stage.split(wire.nbytes)
-                if sizes is not None:
-                    chunks = sizes
-                    infos.append({"stage": "chunk", "chunks": list(sizes)})
-        cost_s = sum(c[1] for c in charges)
-        wire.stages = infos
-        enc = Encoded(wire=wire, cost_s=cost_s,
-                      extra_alloc=sum(c[2] for c in charges),
-                      charges=charges)
-        if chunks is not None:
-            # encode completes proportionally to bytes produced: chunk i
-            # is transferable once its share of the encode work is done
-            cum, plan = 0, []
-            for nb in chunks:
-                cum += nb
-                plan.append((nb, cost_s * cum / wire.nbytes))
-            enc.chunks = plan
-        return enc
+        with obs.span("wire.encode", side=self.host):
+            charges: List[Tuple[str, float, int]] = []
+            infos: List[dict] = []
+            wire: Optional[WireData] = None
+            chunks = None
+            for stage in self._order:
+                with obs.span("wire." + stage.name, side=self.host):
+                    if isinstance(stage, WireCompressStage):
+                        out, info = stage.compress(wire)
+                        if info is not None:
+                            charges.append((
+                                stage.name,
+                                stage.codec.enc_time(info["orig_nbytes"]),
+                                out.nbytes))
+                            infos.append(info)
+                            wire = out
+                    elif isinstance(stage, CompressStage):
+                        orig_nbytes = payload.nbytes
+                        if _pre is not None:
+                            payload, info = _pre
+                        else:
+                            payload, info = stage.compress(payload, peer)
+                        if info is not None:
+                            charges.append((stage.name,
+                                            stage.codec.enc_time(orig_nbytes),
+                                            payload.nbytes))
+                            infos.append(info)
+                    elif isinstance(stage, SerializeStage):
+                        wire = stage.serializer.serialize(payload)
+                        charges.append((
+                            stage.name, stage.serializer.ser_time(wire.nbytes),
+                            0))
+                        infos.append({"stage": "serialize",
+                                      "codec": wire.codec})
+                    elif isinstance(stage, ChunkStage):
+                        sizes = stage.split(wire.nbytes)
+                        if sizes is not None:
+                            chunks = sizes
+                            infos.append({"stage": "chunk",
+                                          "chunks": list(sizes)})
+            cost_s = sum(c[1] for c in charges)
+            wire.stages = infos
+            enc = Encoded(wire=wire, cost_s=cost_s,
+                          extra_alloc=sum(c[2] for c in charges),
+                          charges=charges)
+            if chunks is not None:
+                # encode completes proportionally to bytes produced: chunk i
+                # is transferable once its share of the encode work is done
+                cum, plan = 0, []
+                for nb in chunks:
+                    cum += nb
+                    plan.append((nb, cost_s * cum / wire.nbytes))
+                enc.chunks = plan
+            return enc
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -266,21 +280,23 @@ class Channel:
         (payload, cost_s)."""
         from repro.compression.stages import codec_for
         payload, cur, cost = None, wire, 0.0
-        for info in reversed(self._stage_infos(wire)):
-            kind = info.get("stage", "compress")
-            if kind == "chunk":
-                continue  # reassembly is the transport's job (free here)
-            if kind == "wirecodec":
-                codec = codec_for(info["codec"])
-                cur = codec.decompress_wire(cur, info)
-                cost += codec.dec_time(info["orig_nbytes"])
-            elif kind == "serialize":
-                payload = decode_wire(cur, self.serializer)
-                cost += self.serializer.deser_time(cur.nbytes)
-            else:  # payload-domain compress
-                codec = codec_for(info["codec"])
-                payload = codec.decompress(payload, info)
-                cost += codec.dec_time(info["orig_nbytes"])
+        with obs.span("wire.decode", side=self.host):
+            for info in reversed(self._stage_infos(wire)):
+                kind = info.get("stage", "compress")
+                if kind == "chunk":
+                    continue  # reassembly is the transport's job (free)
+                with obs.span("wire." + kind, side=self.host):
+                    if kind == "wirecodec":
+                        codec = codec_for(info["codec"])
+                        cur = codec.decompress_wire(cur, info)
+                        cost += codec.dec_time(info["orig_nbytes"])
+                    elif kind == "serialize":
+                        payload = decode_wire(cur, self.serializer)
+                        cost += self.serializer.deser_time(cur.nbytes)
+                    else:  # payload-domain compress
+                        codec = codec_for(info["codec"])
+                        payload = codec.decompress(payload, info)
+                        cost += codec.dec_time(info["orig_nbytes"])
         return payload, cost
 
     def encode_batch(self, items: List[Tuple[object, Optional[str]]]
@@ -298,6 +314,13 @@ class Channel:
         grouped per codec and dispatched through ``codec.decode_batch``
         (one fused dequantize for a round's worth of received updates).
         Charges and payloads are identical to per-wire ``decode``."""
+        if not wires:
+            return []
+        with obs.span("wire.decode", side=self.host, wires=len(wires)):
+            return self._decode_batch(wires)
+
+    def _decode_batch(self, wires: List[WireData]
+                      ) -> List[Tuple[object, float]]:
         from repro.compression.stages import codec_for
         results: List[Optional[Tuple[object, float]]] = [None] * len(wires)
         # wire -> payload via the non-payload-codec steps; collect the
@@ -311,12 +334,14 @@ class Channel:
                 if kind == "chunk":
                     continue
                 if kind == "wirecodec":
-                    codec = codec_for(info["codec"])
-                    cur = codec.decompress_wire(cur, info)
-                    cost += codec.dec_time(info["orig_nbytes"])
+                    with obs.span("wire.wirecodec", side=self.host):
+                        codec = codec_for(info["codec"])
+                        cur = codec.decompress_wire(cur, info)
+                        cost += codec.dec_time(info["orig_nbytes"])
                 elif kind == "serialize":
-                    payload = decode_wire(cur, self.serializer)
-                    cost += self.serializer.deser_time(cur.nbytes)
+                    with obs.span("wire.serialize", side=self.host):
+                        payload = decode_wire(cur, self.serializer)
+                        cost += self.serializer.deser_time(cur.nbytes)
                 else:  # payload-domain: defer for the fused dispatch
                     payload_infos.append(info)
                     cost += codec_for(info["codec"]).dec_time(
@@ -329,14 +354,15 @@ class Channel:
             results[idx] = (payload, cost)
         for name, members in tail.items():
             codec = codec_for(name)
-            decoded = codec.decode_batch([p for _, p, _ in members],
-                                         [infos[0] for _, _, infos in
-                                          members])
-            for (idx, _, infos), payload in zip(members, decoded):
-                for info in infos[1:]:
-                    payload = codec_for(info["codec"]).decompress(payload,
-                                                                  info)
-                results[idx] = (payload, results[idx][1])
+            with obs.span("wire.compress", side=self.host):
+                decoded = codec.decode_batch([p for _, p, _ in members],
+                                             [infos[0] for _, _, infos in
+                                              members])
+                for (idx, _, infos), payload in zip(members, decoded):
+                    for info in infos[1:]:
+                        payload = codec_for(info["codec"]).decompress(
+                            payload, info)
+                    results[idx] = (payload, results[idx][1])
         return results
 
     def decode_time(self, wire: WireData) -> float:
@@ -389,12 +415,15 @@ def encode_many(items: List[Tuple[Channel, object, Optional[str]]]
     for (_, _sig), members in groups.items():
         codec = members[0][1].codec
         payloads = [items[i][1] for i, _, _ in members]
-        states = [stage.resolve_state(p, peer)
-                  for (_, stage, peer), p in zip(members, payloads)]
-        for (i, stage, peer), (out, new_state, info) in zip(
-                members, codec.encode_batch(payloads, states)):
-            stage.store_state(peer, new_state)
-            pre[i] = (out, info)
+        hosts = {items[i][0].host for i, _, _ in members}
+        with obs.span("wire.compress",
+                      side=hosts.pop() if len(hosts) == 1 else "many"):
+            states = [stage.resolve_state(p, peer)
+                      for (_, stage, peer), p in zip(members, payloads)]
+            for (i, stage, peer), (out, new_state, info) in zip(
+                    members, codec.encode_batch(payloads, states)):
+                stage.store_state(peer, new_state)
+                pre[i] = (out, info)
     return [ch.encode(payload, peer, _pre=pre[idx])
             for idx, (ch, payload, peer) in enumerate(items)]
 

@@ -8,34 +8,36 @@
                         of buffering O(clients) update trees).
 ``staleness_weight``  — FedBuff-style polynomial discount for async modes.
 ``merge_global``      — staleness-damped server update (event-driven modes).
-Aggregation compute time is measured for the Fig 5 'aggregation' bars.
+Aggregation compute time is measured for the Fig 5 'aggregation' bars,
+by the ``repro.obs`` span around each call (``hub.fedavg``, ``hub.fold``,
+``hub.merge``).
 """
 from __future__ import annotations
 
-import time
 from typing import Optional, Sequence
 
 import jax
 import numpy as np
 
+from repro import obs
 from repro.kernels import ops
 
 
 def fedavg(updates: Sequence, weights, *, interpret=None):
     """updates: list of pytrees; weights ~ num_examples per client."""
-    t0 = time.perf_counter()
-    agg = ops.fedavg_aggregate(updates, weights, interpret=interpret)
-    agg = jax.block_until_ready(agg)
-    return agg, time.perf_counter() - t0
+    with obs.span("hub.fedavg", updates=len(updates)) as sp:
+        agg = ops.fedavg_aggregate(updates, weights, interpret=interpret)
+        agg = jax.block_until_ready(agg)
+    return agg, sp.seconds
 
 
 def fedavg_quantized(packed_list: Sequence[dict], weights, unflatten, *,
                      interpret=None):
-    t0 = time.perf_counter()
-    agg = ops.fedavg_aggregate_q8(packed_list, weights, unflatten,
-                                  interpret=interpret)
-    agg = jax.block_until_ready(agg)
-    return agg, time.perf_counter() - t0
+    with obs.span("hub.fedavg", updates=len(packed_list)) as sp:
+        agg = ops.fedavg_aggregate_q8(packed_list, weights, unflatten,
+                                      interpret=interpret)
+        agg = jax.block_until_ready(agg)
+    return agg, sp.seconds
 
 
 class StreamingAccumulator:
@@ -66,27 +68,31 @@ class StreamingAccumulator:
         self.sum_weight += rec.weight
         self.count += rec.count
         if isinstance(rec.payload, TensorPayload):
-            t0 = time.perf_counter()
-            flat, unflatten = ops.flatten_pytree(rec.payload.tree)
-            if self.acc is None:
-                self.unflatten = unflatten
-                self.acc = ops.fedavg_accumulate_flat(
-                    np.zeros(flat.shape[0], np.float32), flat, eff,
-                    interpret=interpret)
-            else:
-                self.acc = ops.fedavg_accumulate_flat(
-                    self.acc, flat, eff, interpret=interpret)
-            jax.block_until_ready(self.acc)
-            self.agg_s += time.perf_counter() - t0
+            update = (f"{rec.client.client_id}/v{rec.version}"
+                      if rec.client is not None else None)
+            with obs.span("hub.fold", update=update) as sp:
+                flat, unflatten = ops.flatten_pytree(rec.payload.tree)
+                if self.acc is None:
+                    self.unflatten = unflatten
+                    self.acc = ops.fedavg_accumulate_flat(
+                        np.zeros(flat.shape[0], np.float32), flat, eff,
+                        interpret=interpret)
+                else:
+                    self.acc = ops.fedavg_accumulate_flat(
+                        self.acc, flat, eff, interpret=interpret)
+                jax.block_until_ready(self.acc)
+            self.agg_s += sp.seconds
 
-    def merged(self):
-        """-> (merged pytree | None, measured agg seconds)."""
+    def merged(self, **attrs):
+        """-> (merged pytree | None, measured agg seconds). ``attrs`` go
+        on the ``hub.merge`` span beside the updates and weight merged."""
         if self.acc is None or self.sum_eff <= 0:
             return None, self.agg_s
-        t0 = time.perf_counter()
-        tree = self.unflatten(self.acc / np.float32(self.sum_eff))
-        tree = jax.block_until_ready(tree)
-        return tree, self.agg_s + time.perf_counter() - t0
+        with obs.span("hub.merge", updates=self.count, weight=self.sum_eff,
+                      **attrs) as sp:
+            tree = self.unflatten(self.acc / np.float32(self.sum_eff))
+            tree = jax.block_until_ready(tree)
+        return tree, self.agg_s + sp.seconds
 
     def reset(self):
         self.acc = None

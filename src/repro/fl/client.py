@@ -14,13 +14,13 @@ when live.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.message import FLMessage, TensorPayload, VirtualPayload
 
 PCIE_BW = 12e9  # bytes/s host<->device staging
@@ -60,16 +60,38 @@ class FLClient:
 
     # ------------------------------------------------------------------
     def local_train(self, params, local_steps: int):
-        """Live local training. Returns (new_params, mean_loss, seconds)."""
-        t0 = time.perf_counter()
-        it = self.dataset.batches(self.batch_size, seed=self.seed + self._round)
-        losses = []
-        for _ in range(local_steps):
-            batch = {k: jnp.asarray(v) for k, v in next(it).items()}
-            params, loss = self.train_fn(params, batch)
-            losses.append(float(loss))
-        jax.block_until_ready(jax.tree.leaves(params)[0])
-        return params, float(np.mean(losses)), time.perf_counter() - t0
+        """Live local training. Returns (new_params, mean_loss, seconds).
+
+        Each step is a ``client.step`` span split into ``step.input``
+        (the batch draw and its upload), ``step.dispatch`` (the jitted
+        step's call) and ``step.sync`` (the loss read back, which waits
+        for the step); the upload and read-back bytes are counted."""
+        with obs.span("client.local_train", steps=local_steps) as sp:
+            if obs.recording():
+                obs.count("copy.h2d_bytes", sum(
+                    np.asarray(l).nbytes for l in jax.tree.leaves(params)
+                    if not isinstance(l, jax.Array)), site="model")
+            it = self.dataset.batches(self.batch_size,
+                                      seed=self.seed + self._round)
+            losses = []
+            for _ in range(local_steps):
+                with obs.span("client.step"):
+                    with obs.span("step.input"):
+                        host = next(it)
+                        batch = {k: jnp.asarray(v) for k, v in host.items()}
+                    with obs.span("step.dispatch"):
+                        params, loss = self.train_fn(params, batch)
+                    with obs.span("step.sync"):
+                        losses.append(float(loss))
+                if obs.recording():
+                    obs.count("client.steps", 1)
+                    obs.count("copy.h2d_bytes", sum(
+                        np.asarray(v).nbytes for v in host.values()),
+                        site="batch")
+                    obs.count("copy.d2h_bytes", np.dtype(loss.dtype).itemsize,
+                              site="loss")
+            jax.block_until_ready(jax.tree.leaves(params)[0])
+        return params, float(np.mean(losses)), sp.seconds
 
     # ------------------------------------------------------------------
     def run_round(self, msg: FLMessage, ready_t: float, local_steps: int,
@@ -77,6 +99,13 @@ class FLClient:
         """Handle one received global model; returns (update_msg, timing,
         send_start_t). Works in live or simulated mode depending on the
         payload type."""
+        version = msg.metadata.get("version", msg.round)
+        with obs.span("client.update",
+                      update=f"{self.client_id}/v{version}"):
+            return self._run_round(msg, ready_t, local_steps, server_id,
+                                   version)
+
+    def _run_round(self, msg, ready_t, local_steps, server_id, version):
         self._round = msg.round
         timing = ClientTiming()
         payload = msg.payload
@@ -112,6 +141,5 @@ class FLClient:
                            metadata={"num_examples": num_examples,
                                      # global version this update was
                                      # trained against (async staleness)
-                                     "version": msg.metadata.get(
-                                         "version", msg.round)})
+                                     "version": version})
         return update, timing, t

@@ -30,6 +30,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.message import FLMessage, TensorPayload, VirtualPayload
 from repro.fl.aggregator import (fedavg, merge_global, simulated_agg_time,
                                  staleness_weight)
@@ -386,7 +387,8 @@ class FLScheduler:
         if _attempt == 0 and self._cohort_blocked(client.client_id):
             return  # not sampled this round (or its pipeline is live)
         self._mark_busy(client.client_id, True)
-        h = self.backend.isend(self._model_msg(client), now)
+        with obs.span("hub.broadcast", clients=1):
+            h = self.backend.isend(self._model_msg(client), now)
         if not self._track(h, f"model>{client.client_id}",
                            self._on_client_recv, client=client,
                            gen=self._gen[client.client_id]):
@@ -417,7 +419,8 @@ class FLScheduler:
         for c in clients:
             self._mark_busy(c.client_id, True)
         msgs = [self._model_msg(c) for c in clients]
-        _, arrives = self.backend.broadcast(msgs, now)
+        with obs.span("hub.broadcast", clients=len(msgs)):
+            _, arrives = self.backend.broadcast(msgs, now)
         self.loop.call_at_many(
             [(arrive, f"model>{c.client_id}", self._on_client_recv,
               dict(client=c, gen=self._gen[c.client_id]))
@@ -566,7 +569,10 @@ class FLScheduler:
     def aggregate(self, records: Sequence[UpdateRecord], now: float) -> float:
         """Staleness-weighted buffered aggregate; bumps the global version.
         Returns the simulated completion time."""
-        records = list(records)
+        with obs.span("sched.aggregate", version=self.version + 1):
+            return self._aggregate(list(records), now)
+
+    def _aggregate(self, records: List[UpdateRecord], now: float) -> float:
         if self.finished or not records:
             return now
         alphas = [self.strategy.staleness_weight(r.staleness)
@@ -579,7 +585,7 @@ class FLScheduler:
         if acc is not None and acc.count:
             # streaming hub: the buffer is already folded into the
             # accumulator; merge = one divide + damped server update
-            merged, stream_agg_s = acc.merged()
+            merged, stream_agg_s = acc.merged(version=self.version + 1)
             if merged is not None and acc.sum_eff > 0:
                 agg_s = stream_agg_s
                 lam = self.server_lr * (acc.sum_eff /
@@ -593,7 +599,9 @@ class FLScheduler:
                     nbytes, tag=f"model:v{self.version + 1}")
             acc.reset()
         elif len(trees) == len(records) and sum(eff) > 0:
-            merged, agg_s = fedavg(trees, eff)
+            with obs.span("hub.merge", updates=len(records), weight=sum(eff),
+                          version=self.version + 1):
+                merged, agg_s = fedavg(trees, eff)
             lam = self.server_lr * (sum(eff) /
                                     max(sum(r.weight for r in records), 1e-12))
             self.global_params = merge_global(self.global_params, merged, lam)

@@ -13,6 +13,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.backends.base import CommBackend
 from repro.core.message import (FLMessage, TensorPayload, VirtualPayload)
 from repro.core.netsim import Region, Transfer, simulate_transfers
@@ -51,6 +52,9 @@ class FLServer:
         self.reports: List[RoundReport] = []
         self.global_params = None
         self.round = 0
+        # host seconds of the latest run_round / run_async call, read
+        # from its ``round`` / ``sched.run`` span
+        self.wall_s = 0.0
 
     # ------------------------------------------------------------------
     def _client_backend(self, client: FLClient, msg=None):
@@ -159,6 +163,12 @@ class FLServer:
                   participants: Optional[Sequence[FLClient]] = None):
         """One FL round. ``global_payload``: TensorPayload | VirtualPayload.
         Returns RoundReport (and updates self.global_params in live mode)."""
+        with obs.span("round", round=self.round) as sp:
+            report = self._run_round(global_payload, dropped, participants)
+        self.wall_s = sp.seconds
+        return report
+
+    def _run_round(self, global_payload, dropped, participants):
         dropped = dropped or set()
         clients = list(participants or self.clients)
         t0 = self.now
@@ -168,7 +178,8 @@ class FLServer:
         msgs = [FLMessage("model_sync", "server", c.client_id,
                           round=self.round, payload=global_payload)
                 for c in clients]
-        sender_done, _ = self.backend.broadcast(msgs, t0)
+        with obs.span("hub.broadcast", clients=len(msgs)):
+            sender_done, _ = self.backend.broadcast(msgs, t0)
 
         # 2) clients receive, train, stage updates
         sends, timings = [], {}
@@ -266,7 +277,10 @@ class FLServer:
                             availability=availability,
                             cohort_k=cohort_k, cohort_seed=cohort_seed,
                             streaming_hub=streaming_hub)
-        report = sched.run(global_payload, **limits)
+        with obs.span("sched.run",
+                      mode=getattr(strategy, "name", "?")) as sp:
+            report = sched.run(global_payload, **limits)
+        self.wall_s = sp.seconds
         if sched.global_params is not None:
             self.global_params = sched.global_params
         self.now = sched.loop.now

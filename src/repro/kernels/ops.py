@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels import fedavg_reduce as fr
 from repro.kernels import quantize as qz
 from repro.kernels import ref as kref
@@ -60,6 +61,15 @@ def dequantize_flat(packed, *, out_dtype=jnp.float32, interpret=None):
     s = packed["scales"].reshape(-1, 1)
     x = qz.dequantize_blocks(q, s, out_dtype=out_dtype, interpret=interpret)
     return x.reshape(-1)[: packed["orig_len"]]
+
+
+def _count_copies(site: str, inputs, h2d: int, d2h: int) -> None:
+    """Count one batched call's host<->device crossings in bytes: the
+    arrays uploaded (``h2d``) and fetched (``d2h``), plus the ``inputs``
+    that arrive on the device and are read to the host."""
+    obs.count("copy.d2h_bytes", d2h + sum(
+        x.nbytes for x in inputs if isinstance(x, jax.Array)), site=site)
+    obs.count("copy.h2d_bytes", h2d, site=site)
 
 
 # ---------------------------------------------------------------------------
@@ -121,6 +131,8 @@ def quantize_flat_batch(flats: Sequence, *, block: int = 256,
         off += pl
     q, s = _quantize_rows(jnp.asarray(big.reshape(-1, block)), interpret)
     q, s = np.asarray(q), np.asarray(s)  # one transfer; slices are views
+    if obs.recording():
+        _count_copies("quantize", flats, big.nbytes, q.nbytes + s.nbytes)
     out, row = [], 0
     for a, pl in zip(arrs, pad_lens):
         rows = pl // block
@@ -149,6 +161,10 @@ def dequantize_flat_batch(packed_list: Sequence[dict], *,
     if out_dtype != jnp.float32:
         x = x.astype(out_dtype)
     x = np.asarray(x)
+    if obs.recording():
+        _count_copies("dequantize", [a for p in packed_list
+                                     for a in (p["q"], p["scales"])],
+                      q.nbytes + s.nbytes, x.nbytes)
     out, row = [], 0
     for p, qi in zip(packed_list, qs):
         rows = qi.shape[0]
@@ -186,6 +202,9 @@ def topk_flat_batch(flats: Sequence, *, k_frac: float = 0.05):
         stacked = jnp.asarray(np.stack([arrs[i] for i in idxs]))
         gi, gv = _jit_topk(stacked, k=k)
         gi, gv = np.asarray(gi), np.asarray(gv)
+        if obs.recording():
+            _count_copies("topk", [flats[i] for i in idxs], stacked.nbytes,
+                          gi.nbytes + gv.nbytes)
         for row, i in enumerate(idxs):
             out[i] = {"idx": gi[row], "vals": gv[row], "n": size}
     return out

@@ -32,12 +32,12 @@ import argparse
 import dataclasses
 import json
 import math
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import FLConfig
 from repro.configs.paper_tiers import TIERS, build_tier_model
 from repro.core import FLMessage, TensorPayload
@@ -201,16 +201,14 @@ def run_event_driven(fl_cfg: FLConfig, server: FLServer, params, store,
         fl_cfg.availability_trace,
         [c.client_id for c in server.clients],
         horizon_s=scenario.faults.trace_horizon_s, seed=fl_cfg.seed)
-    t0 = time.perf_counter()
     report, sched = server.run_async(global_payload, strategy,
                                      availability=availability,
                                      cohort_k=fl_cfg.cohort_k,
                                      cohort_seed=fl_cfg.seed,
                                      streaming_hub=fl_cfg.streaming_hub,
                                      max_aggregations=fl_cfg.rounds)
-    wall = time.perf_counter() - t0
     print(f"[fl:{report.mode}] backend={report.backend} "
-          f"wall={wall:.3f}s sim_time={report.sim_time:.2f}s "
+          f"wall={server.wall_s:.3f}s sim_time={report.sim_time:.2f}s "
           f"aggregations={report.n_aggregations} "
           f"client_updates={report.n_client_updates} "
           f"(effective {report.effective_updates:.2f}, "
@@ -350,6 +348,10 @@ def _parser() -> argparse.ArgumentParser:
     ap.add_argument("--relay-depth", type=int, default=None,
                     help="hier mode: relay-tree levels (1 = the "
                          "single-tier relay)")
+    ap.add_argument("--spans", default=None, metavar="PATH",
+                    help="record the run's spans and counters "
+                         "(repro.obs) and write them to PATH as JSONL "
+                         "when the run ends")
     return ap
 
 
@@ -407,6 +409,16 @@ def resolve_scenario(args, ap: argparse.ArgumentParser) -> Scenario:
 def main(argv=None):
     ap = _parser()
     args = ap.parse_args(argv)
+    rec = obs.enable() if args.spans else None
+    try:
+        return _main(args, ap)
+    finally:
+        if rec is not None:
+            obs.disable()
+            rec.write_jsonl(args.spans)
+
+
+def _main(args, ap: argparse.ArgumentParser):
     enable_compile_cache()
     if args.sweep:
         # a sweep file is a whole grid of scenarios, not one training
@@ -473,14 +485,12 @@ def main(argv=None):
         dropped, stragglers = fault.for_round(r, [c.client_id for c in
                                                   server.clients])
         apply_stragglers(server.clients, stragglers, fault.straggler_factor)
-        t0 = time.perf_counter()
         report = server.run_round(TensorPayload(params), dropped=dropped)
         if server.global_params is not None:
             params = server.global_params
-        jax.block_until_ready(params)
-        wall = time.perf_counter() - t0
         losses.append(report.losses)
-        print(f"[fl] round {r}: wall={wall:.3f}s t={report.round_time:8.2f}s sim "
+        print(f"[fl] round {r}: wall={server.wall_s:.3f}s "
+              f"t={report.round_time:8.2f}s sim "
               f"loss={report.losses if report.losses else float('nan'):.4f} "
               f"participants={report.n_participants} "
               f"server_mem={report.peak_server_memory / 2**20:.1f}MB "
