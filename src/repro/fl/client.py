@@ -22,6 +22,7 @@ import numpy as np
 
 from repro import obs
 from repro.core.message import FLMessage, TensorPayload, VirtualPayload
+from repro.fl.flat import FlatParams
 
 PCIE_BW = 12e9  # bytes/s host<->device staging
 
@@ -65,7 +66,12 @@ class FLClient:
         Each step is a ``client.step`` span split into ``step.input``
         (the batch draw and its upload), ``step.dispatch`` (the jitted
         step's call) and ``step.sync`` (the loss read back, which waits
-        for the step); the upload and read-back bytes are counted."""
+        for the step); the upload and read-back bytes are counted.
+
+        A ``train_fn`` may return the parameters as a ``FlatParams``
+        carrier and take it back on the next step (``client.flat_steps``
+        counts those calls); the epoch's result is then turned back into
+        the model's tree (``step.unpack``)."""
         with obs.span("client.local_train", steps=local_steps) as sp:
             if obs.recording():
                 obs.count("copy.h2d_bytes", sum(
@@ -79,17 +85,23 @@ class FLClient:
                     with obs.span("step.input"):
                         host = next(it)
                         batch = {k: jnp.asarray(v) for k, v in host.items()}
+                    flat = isinstance(params, FlatParams)
                     with obs.span("step.dispatch"):
                         params, loss = self.train_fn(params, batch)
                     with obs.span("step.sync"):
                         losses.append(float(loss))
                 if obs.recording():
                     obs.count("client.steps", 1)
+                    if flat:
+                        obs.count("client.flat_steps", 1)
                     obs.count("copy.h2d_bytes", sum(
                         np.asarray(v).nbytes for v in host.values()),
                         site="batch")
                     obs.count("copy.d2h_bytes", np.dtype(loss.dtype).itemsize,
                               site="loss")
+            if isinstance(params, FlatParams):
+                with obs.span("step.unpack"):
+                    params = params.tree()
             jax.block_until_ready(jax.tree.leaves(params)[0])
         return params, float(np.mean(losses)), sp.seconds
 

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
 import math
 
@@ -45,9 +46,36 @@ from repro.core.backends import BACKEND_NAMES
 from repro.data import make_silo_datasets
 from repro.fl import FLClient, FLServer, make_strategy
 from repro.fl.fault import FaultPlan, apply_stragglers, make_availability
+from repro.fl.flat import FlatParams
 from repro.launch.compile_cache import enable_compile_cache
 from repro.scenario import (TOPOLOGY_PRESETS, Scenario, ScenarioError,
                             build_runtime, with_overrides)
+
+
+def make_train_step(model):
+    """The clients' step: ``step(params, batch) -> (FlatParams, loss)``,
+    taking the model's tree (packed first) or a ``FlatParams``. Between
+    steps the parameters stay one flat buffer per dtype, so a launch of
+    the jitted ``train_fn`` (kept as ``step.train_fn``) passes a few
+    buffers instead of one per leaf."""
+    @functools.partial(jax.jit, static_argnums=0)
+    def train_fn(layout, buffers, batch):
+        def loss_fn(p):
+            loss, _ = model.loss(p, batch)
+            return loss
+        loss, grads = jax.value_and_grad(loss_fn)(layout.unflatten(buffers))
+        new = tuple(b - 0.05 * g
+                    for b, g in zip(buffers, layout.flatten(grads)))
+        return new, loss
+
+    def train_step(params, batch):
+        if not isinstance(params, FlatParams):
+            with obs.span("step.pack"):
+                params = FlatParams.pack(params)
+        buffers, loss = train_fn(params.layout, params.buffers, batch)
+        return FlatParams(params.layout, buffers=buffers), loss
+    train_step.train_fn = train_fn
+    return train_step
 
 
 def build_deployment(fl_cfg: FLConfig, *, tier: str = "small",
@@ -103,14 +131,7 @@ def build_deployment(fl_cfg: FLConfig, *, tier: str = "small",
 
     # one compiled step for the whole deployment: every client trains the
     # same model on same-shaped batches
-    @jax.jit
-    def train_fn(params, batch):
-        def loss_fn(p):
-            loss, _ = model.loss(p, batch)
-            return loss
-        loss, grads = jax.value_and_grad(loss_fn)(params)
-        params2 = jax.tree.map(lambda p, g: p - 0.05 * g, params, grads)
-        return params2, loss
+    train_step = make_train_step(model)
 
     # event-driven modes charge the tier-calibrated training time instead
     # of measured wall seconds ("live compute, simulated clock"): jit
@@ -136,7 +157,7 @@ def build_deployment(fl_cfg: FLConfig, *, tier: str = "small",
     for i, host in enumerate(env.clients):
         cb = rt.make_backend(host.host_id, compression=client_compression)
         clients.append(FLClient(host.host_id, cb, dataset=silos[i],
-                                train_fn=train_fn, batch_size=16,
+                                train_fn=train_step, batch_size=16,
                                 sim_train_s=sim_train,
                                 seed=fl_cfg.seed + i))
     server_backend = rt.make_backend("server",
