@@ -9,9 +9,10 @@ ResNet56 at its published configuration over 7 geo-distributed silos, for
 3 sync rounds and for 3 fedbuff aggregations with qsgd and the streaming
 hub.
 
-Four chips: only the cross-pod ``fl_round`` step, the pod axis over the
-chips (chips stand in for silos), int8 delta exchange against the f32
-exchange it is compared with.
+Four chips: only the cross-pod FL round, through ``repro.launch.fl_round``
+with HuBERT X-Large as published, the pod axis over the chips (chips
+stand in for silos): the int8 delta exchange against the f32 exchange it
+is compared with.
 
 Everything runs in this one process, because a TPU chip belongs to one
 process at a time. The last line of stdout is
@@ -23,7 +24,6 @@ from __future__ import annotations
 
 import argparse
 import contextlib
-import dataclasses
 import functools
 import io
 import json
@@ -53,17 +53,10 @@ FL_RUNS = {
         "--mode", "fedbuff", "--compression", "qsgd", "--streaming-hub"],
 }
 
-# The four-chip phase: HuBERT X-Large (arXiv:2106.07447) at its published
-# widths (d_model 1280, 16 heads, FFN 5120). Depth is cut from 48 to 44
-# layers, the most that fits: with Adam state each pod's replica takes
-# ~0.29 GiB a layer, and compiled for a described v5e, 48 layers need
-# 19.65 GB of the chip's 15.75 GiB while 44 take 12.91 GiB of arguments
-# and 2.95 GiB of temporaries.
-CROSSPOD_ARCH = "hubert-xlarge"
-CROSSPOD_LAYERS = 44
-CROSSPOD_SEQ = 512  # frames: ~10 s of 50 Hz features
-CROSSPOD_POD_BATCH = 4
-CROSSPOD_LOCAL_STEPS = 2
+# The four-chip phase: the cross-pod FL round through repro.launch.fl_round,
+# HuBERT X-Large as published (48 layers), one pod per chip
+CROSSPOD_ARGV = ["--arch", "hubert-xlarge", "--pods", "4", "--local-steps",
+                 "2", "--pod-batch", "4", "--samples", "160000"]
 CROSSPOD_ROUNDS = 3
 # int8 losses must track f32 within this relative gap: the shared-scale
 # int8 delta errs by at most max|delta|/254 per element (0.4% of the
@@ -275,32 +268,12 @@ def run_phases(phases):
 # four chips: the cross-pod FL round
 # ---------------------------------------------------------------------------
 
-def crosspod(cfg, *, n_pods: int = 4, rounds: int = CROSSPOD_ROUNDS,
-             local_steps: int = CROSSPOD_LOCAL_STEPS,
-             pod_batch: int = CROSSPOD_POD_BATCH, seq: int = CROSSPOD_SEQ,
-             seed: int = 0):
-    """``make_fl_round_step`` with the pod axis over ``n_pods`` devices,
-    f32 exchange then int8 from the same seed and batches. Asserts the
-    arrays span every device, pods agree after each sync, and int8 losses
-    track f32."""
-    from repro.configs.base import MeshConfig, ShapeConfig, TrainConfig
-    from repro.launch.mesh import make_mesh
-    from repro.launch.step_builders import make_fl_round_step
-    from repro.optim.optimizers import adamw_init
-
-    devices = jax.devices()[:n_pods]
-    mcfg = MeshConfig(shape=(n_pods, 1, 1),
-                      axis_names=("pod", "data", "model"))
-    mesh = make_mesh(mcfg, devices=devices)
-    shape = ShapeConfig(name="crosspod", seq_len=seq,
-                        global_batch=n_pods * pod_batch, kind="train")
-    rng = np.random.default_rng(seed)
-    lead = (n_pods, local_steps, pod_batch, seq)
-    # frame embeddings and 32 frame classes in use: the loss can fall
-    batches = [{"embeds": rng.normal(size=lead + (cfg.d_model,)).astype(
-                    jnp.dtype(cfg.dtype)),
-                "targets": rng.integers(0, 32, size=lead).astype(np.int32)}
-               for _ in range(rounds)]
+def crosspod(rounds: int = CROSSPOD_ROUNDS):
+    """``repro.launch.fl_round``'s round with the f32 exchange, then int8,
+    from the same seed and batches. Asserts the arrays span every chip,
+    the pods agree after each sync, and int8 losses track f32."""
+    from repro import obs
+    from repro.launch import fl_round
 
     @jax.jit
     def drift(stacked):
@@ -311,47 +284,34 @@ def crosspod(cfg, *, n_pods: int = 4, rounds: int = CROSSPOD_ROUNDS,
 
     losses = {}
     for comp in ("none", "int8"):
-        tcfg = TrainConfig(learning_rate=3e-4, warmup_steps=1,
-                           total_steps=rounds * local_steps,
-                           crosspod_compression=comp)
-        b = make_fl_round_step(cfg, shape, mesh, mcfg, tcfg,
-                               local_steps=local_steps)
-        ps_sh, os_sh, a_sh, b_sh, _ = b.in_shardings
-        with mesh:
-            anchor = jax.jit(lambda k: b.model.init(k)[0],
-                             out_shardings=a_sh)(jax.random.key(seed))
-            params = jax.jit(lambda a: jax.tree.map(
-                lambda x: jnp.broadcast_to(x[None], (n_pods,) + x.shape), a),
-                out_shardings=ps_sh)(anchor)
-            opt = jax.jit(jax.vmap(lambda p: adamw_init(p, tcfg)),
-                          out_shardings=os_sh)(params)
-            leaf = jax.tree.leaves(params)[0]
-            held = {s.device for s in leaf.addressable_shards}
-            if held != set(devices):
+        run = fl_round.build(fl_round.parse_args(
+            CROSSPOD_ARGV + ["--compression", comp]))
+        if comp == "none":
+            print(f"[crosspod] {run.cfg.name}: {run.cfg.num_layers} layers, "
+                  f"{run.cfg.param_count()} params per pod, "
+                  f"{run.pod_batch} x {run.samples} samples ({run.frames} "
+                  f"frames) per pod per step, {run.local_steps} local steps "
+                  f"per round")
+        rec = obs.enable()
+        try:
+            run.start()
+            held = {s.device for s in
+                    jax.tree.leaves(run.params)[0].addressable_shards}
+            if held != set(run.devices):
                 raise AssertionError(f"stacked params on {len(held)} "
-                                     f"devices, want all {n_pods}")
-            step = jax.jit(b.fn, in_shardings=b.in_shardings,
-                           out_shardings=b.out_shardings,
-                           donate_argnums=(0, 1, 2))
-            t0 = time.perf_counter()
-            first = jax.device_put(batches[0], b_sh)
-            compiled = step.lower(params, opt, anchor, first,
-                                  jnp.int32(0)).compile()
-            compile_s = time.perf_counter() - t0
+                                     f"devices, want all {run.pods}")
             ls, walls, drifts = [], [], []
-            for r in range(rounds):
-                batch = jax.device_put(batches[r], b_sh)
+            for _ in range(rounds):
                 t0 = time.perf_counter()
-                params, opt, anchor, loss = compiled(
-                    params, opt, anchor, batch, jnp.int32(r * local_steps))
-                jax.block_until_ready((params, opt, anchor, loss))
+                ls.append(run.round())
                 walls.append(time.perf_counter() - t0)
-                ls.append(float(loss))
-                drifts.append(float(drift(params)))
-            del params, opt, anchor
+                drifts.append(float(drift(run.params)))
+        finally:
+            obs.disable()
+            run.release()
         peak = [d.memory_stats().get("peak_bytes_in_use")
-                if d.memory_stats() else None for d in devices]
-        print(f"[crosspod] {comp}: compile_s={compile_s!r} "
+                if d.memory_stats() else None for d in run.devices]
+        print(f"[crosspod] {comp}: compile_s={rec.total('jax.compile_s')!r} "
               f"round_wall_s={walls} losses={ls} pod_drift={drifts} "
               f"peak_bytes_in_use={peak}")
         if not all(math.isfinite(l) for l in ls):
@@ -367,14 +327,7 @@ def crosspod(cfg, *, n_pods: int = 4, rounds: int = CROSSPOD_ROUNDS,
 
 
 def four_chips():
-    from repro.configs import get_config
-    cfg = dataclasses.replace(get_config(CROSSPOD_ARCH),
-                              num_layers=CROSSPOD_LAYERS)
-    print(f"[crosspod] {CROSSPOD_ARCH}: {CROSSPOD_LAYERS} of 48 layers, "
-          f"{cfg.param_count()} params per pod, seq {CROSSPOD_SEQ}, "
-          f"{CROSSPOD_POD_BATCH} sequences per pod per step, "
-          f"{CROSSPOD_LOCAL_STEPS} local steps per round")
-    return run_phases([("crosspod", functools.partial(crosspod, cfg))])
+    return run_phases([("crosspod", crosspod)])
 
 
 def main(argv=None):

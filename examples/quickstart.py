@@ -10,7 +10,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_ORDER, smoke_config
 from repro.configs.base import SMOKE_MESH, ShapeConfig, TrainConfig
-from repro.data import lm_batch_iterator
+from repro.data import audio_stream, lm_batch_iterator
 from repro.launch.mesh import make_smoke_mesh
 from repro.launch.step_builders import make_train_step
 from repro.optim.optimizers import adamw_init
@@ -23,7 +23,8 @@ def main():
     print(f"[quickstart] arch={arch} (reduced: {cfg.num_layers} layers, "
           f"d={cfg.d_model})")
 
-    shape = ShapeConfig(name="qs", seq_len=64, global_batch=8, kind="train")
+    seq = 10_320 if cfg.family == "audio" else 64  # samples or tokens
+    shape = ShapeConfig(name="qs", seq_len=seq, global_batch=8, kind="train")
     tcfg = TrainConfig(learning_rate=3e-3, warmup_steps=5, total_steps=40)
     mesh = make_smoke_mesh()
     bundle = make_train_step(cfg, shape, mesh, SMOKE_MESH, tcfg)
@@ -32,17 +33,14 @@ def main():
     params, _ = model.init(jax.random.key(0))
     opt = adamw_init(params, tcfg)
     step_fn = jax.jit(bundle.fn)
-    data = lm_batch_iterator(0, 8, 64, cfg.vocab_size)
+    data = (audio_stream(cfg, 0, 8, seq) if cfg.family == "audio" else
+            lm_batch_iterator(0, 8, seq, cfg.vocab_size))
 
     losses = []
     with mesh:
         for step in range(40):
             raw = next(data)
             batch = {k: jnp.asarray(v) for k, v in raw.items()}
-            if cfg.external_embeddings:
-                batch = {"embeds": jax.random.normal(
-                    jax.random.key(step), (8, 64, cfg.d_model), jnp.bfloat16),
-                    "targets": batch["targets"]}
             if cfg.family == "vlm":
                 batch["image_embeds"] = jnp.zeros(
                     (8, cfg.num_image_tokens, cfg.d_model), jnp.bfloat16)

@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_ORDER, smoke_config
+from repro.data import audio_stream
 from repro.models import build_model
 
 
@@ -15,15 +16,16 @@ def check(name):
     params, axes = model.init(rng)
     n = sum(int(jnp.size(l)) for l in jax.tree.leaves(params))
     b, s = 2, 16
-    batch = {}
-    if cfg.external_embeddings:
-        batch["embeds"] = jax.random.normal(rng, (b, s, cfg.d_model), jnp.bfloat16)
+    if cfg.family == "audio":  # 20 frames of waveform
+        batch = next(audio_stream(cfg, 0, b, 6_480))
     else:
-        batch["tokens"] = jax.random.randint(rng, (b, s), 0, cfg.vocab_size)
+        batch = {"tokens": jax.random.randint(rng, (b, s), 0,
+                                              cfg.vocab_size),
+                 "targets": jax.random.randint(rng, (b, s), 0,
+                                               cfg.vocab_size)}
     if cfg.family == "vlm":
         batch["image_embeds"] = jax.random.normal(
             rng, (b, cfg.num_image_tokens, cfg.d_model), jnp.bfloat16)
-    batch["targets"] = jax.random.randint(rng, (b, s), 0, cfg.vocab_size)
 
     loss, metrics = jax.jit(model.loss)(params, batch)
     assert jnp.isfinite(loss), (name, loss)

@@ -61,9 +61,13 @@ LLAMA4_MAVERICK = ModelConfig(
     capacity_factor=1.25)
 
 HUBERT_XLARGE = ModelConfig(
-    name="hubert-xlarge", family="audio",  # [arXiv:2106.07447; unverified]
+    name="hubert-xlarge", family="audio",  # [arXiv:2106.07447, Table 1]
     num_layers=48, d_model=1280, num_heads=16, num_kv_heads=16, d_ff=5120,
-    vocab_size=504, causal=False, external_embeddings=True)
+    vocab_size=500, causal=False, mlp_gelu=True, norm="layer",
+    proj_bias=True, rope=False, conv_channels=512,
+    conv_kernels=(10, 3, 3, 3, 3, 2, 2), conv_strides=(5, 2, 2, 2, 2, 2, 2),
+    pos_conv_kernel=128, pos_conv_groups=16, final_dim=1024, logit_temp=0.1,
+    mask_prob=0.08, mask_length=10, feature_pen_weight=10.0)
 
 LLAMA32_VISION_11B = ModelConfig(
     name="llama-3.2-vision-11b", family="vlm",  # [hf:meta-llama; unverified]
@@ -113,6 +117,13 @@ def smoke_config(name: str) -> ModelConfig:
         return dataclasses.replace(
             cfg, name=f"{cfg.name}-smoke", num_layers=2 * cfg.cross_attn_every,
             d_ff=128, num_image_tokens=8, **common)
-    # dense / audio
+    if cfg.family == "audio":
+        # the published kernels, strides and positional conv; narrow
+        # widths
+        return dataclasses.replace(
+            cfg, name=f"{cfg.name}-smoke", num_layers=2, d_ff=128,
+            conv_channels=32, final_dim=32,
+            **{**common, "num_kv_heads": 4})
+    # dense
     return dataclasses.replace(cfg, name=f"{cfg.name}-smoke", num_layers=2,
                                d_ff=128, **common)

@@ -51,8 +51,29 @@ class ModelConfig:
     num_image_tokens: int = 0
     vision_d_model: int = 0
 
-    # audio (encoder-only): inputs are precomputed frame embeddings
+    # encoder-only stacks fed precomputed embeddings (the ViT tier)
     external_embeddings: bool = False
+
+    # audio (HuBERT, arXiv:2106.07447): a waveform CNN encoder (conv ->
+    # LayerNorm over channels -> GELU per layer), a grouped, weight-normed
+    # convolutional positional embedding, and masked prediction of
+    # ``vocab_size`` cluster ids with cosine logits over ``final_dim``.
+    # For this family ``ShapeConfig.seq_len`` counts waveform samples.
+    conv_channels: int = 0
+    conv_kernels: tuple = ()
+    conv_strides: tuple = ()
+    pos_conv_kernel: int = 0
+    pos_conv_groups: int = 0
+    final_dim: int = 0
+    logit_temp: float = 0.1
+    mask_prob: float = 0.0  # chance that a frame starts a masked span
+    mask_length: int = 0  # frames per masked span
+    feature_pen_weight: float = 0.0  # weight of the CNN features' L2
+
+    # block options; the defaults are the decoder zoo's blocks
+    norm: str = "rms"  # rms | layer (LayerNorm with a bias)
+    proj_bias: bool = False  # biases on q/k/v/o and the MLP matrices
+    rope: bool = True  # rotary position embedding on q and k
 
     # embeddings / io
     tie_embeddings: bool = False

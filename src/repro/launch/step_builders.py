@@ -7,6 +7,7 @@ multi-pod dry-run (``launch/dryrun.py``) and the roofline analysis.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Optional
 
@@ -33,6 +34,7 @@ class StepBundle:
     model: object
     plan: MeshPlan
     abstract_state: object  # params/opt/cache shape pytrees (for reports)
+    exchange_bytes: int = 0  # fl_round: what one pod sends per round
 
 
 def _shardings(mesh, plan: MeshPlan, axes_tree, shapes_tree):
@@ -203,50 +205,36 @@ def make_fl_round_step(cfg: ModelConfig, shape: ShapeConfig, mesh,
             isinstance(e, (str, type(None))) for e in x)))
 
     def local_steps_fn(params, opt_state, batches, step):
-        def one(carry, mb):
+        def one(carry, xs):
             p, o = carry
+            mb, i = xs
             (loss, _), g = jax.value_and_grad(
                 lambda pp: model.loss(pp, mb), has_aux=True)(p)
-            lr = cosine_warmup(step, base_lr=train_cfg.learning_rate,
+            lr = cosine_warmup(step + i, base_lr=train_cfg.learning_rate,
                                warmup_steps=train_cfg.warmup_steps,
                                total_steps=train_cfg.total_steps)
             p, o, _ = adamw_update(g, o, p, lr, train_cfg)
             return (p, o), loss
 
-        (params, opt_state), losses = jax.lax.scan(one, (params, opt_state),
-                                                   batches)
+        (params, opt_state), losses = jax.lax.scan(
+            one, (params, opt_state), (batches, jnp.arange(local_steps)))
         return params, opt_state, losses.mean()
 
     def fl_round(params_stacked, opt_stacked, anchor, batches, step):
         new_p, new_o, loss = jax.vmap(local_steps_fn,
                                       in_axes=(0, 0, 0, None))(
             params_stacked, opt_stacked, batches, step)
-        # cross-pod delta exchange. 'int8': deltas quantised with a shared
-        # scale; the exchange is forced to carry 1-byte payloads by
-        # replicating the int8 tensor across the pod axis (all-gather of
-        # int8) and reducing locally in int32 — summing before the
-        # collective would silently promote the wire traffic to 4-byte ints.
         def sync(anchor_leaf, stacked_leaf, axes_leaf):
             delta = (stacked_leaf.astype(jnp.float32)
                      - anchor_leaf.astype(jnp.float32)[None])
-            if train_cfg.crosspod_compression == "int8":
-                scale = jnp.max(jnp.abs(delta)) / 127.0 + 1e-12
-                q = jnp.clip(jnp.round(delta / scale), -127, 127).astype(
-                    jnp.int8)
-                base = plan.spec(tuple(axes_leaf or ()),
-                                 tuple(anchor_leaf.shape))
-                repl = P(*((None,) + tuple(base)))
-                # the barrier pins q as a materialised pod-sharded int8
-                # tensor BEFORE the resharding constraint; without it the
-                # partitioner all-gathers the f32 delta and requantises per
-                # pod (4x the DCN payload, observed in the lowered HLO)
-                q = jax.lax.optimization_barrier(q)
-                q = jax.lax.with_sharding_constraint(
-                    q, NamedSharding(mesh, repl))  # int8 all-gather over pod
-                mean = (jnp.sum(q.astype(jnp.int32), axis=0).astype(
-                    jnp.float32) * scale / n_pods)
-            else:
-                mean = jnp.mean(delta, axis=0)
+            base = plan.spec(tuple(axes_leaf or ()),
+                             tuple(anchor_leaf.shape))
+            replicated = NamedSharding(mesh, P(*((None,) + tuple(base))))
+            mean = exchange_delta(
+                delta, compression=train_cfg.crosspod_compression,
+                n_pods=n_pods,
+                gather=lambda q: jax.lax.with_sharding_constraint(
+                    q, replicated))
             new_anchor = anchor_leaf.astype(jnp.float32) + mean
             return new_anchor.astype(anchor_leaf.dtype)
 
@@ -274,9 +262,37 @@ def make_fl_round_step(cfg: ModelConfig, shape: ShapeConfig, mesh,
     out_shardings = (ps_shard, os_shard, a_shard, step_sh)
     lower_args = (ps_shapes, os_shapes, p_shapes, bs_specs,
                   jax.ShapeDtypeStruct((), jnp.int32))
+    sizes = [math.prod(s.shape) for s in jax.tree.leaves(p_shapes)]
+    if train_cfg.crosspod_compression == "int8":
+        sent = sum(sizes) + 4 * len(sizes)  # int8 codes + an f32 max each
+    else:
+        sent = 4 * sum(sizes)
     return StepBundle(fl_round, lower_args, in_shardings, out_shardings,
                       model, plan_stacked,
-                      {"params": ps_shapes, "opt": os_shapes})
+                      {"params": ps_shapes, "opt": os_shapes},
+                      exchange_bytes=sent)
+
+
+def exchange_delta(delta, *, compression: str, n_pods: int, gather):
+    """The pods' mean of a leaf's ``(n_pods, ...)`` float32 delta, as the
+    cross-pod exchange carries it.
+
+    'int8': one scale shared by the pods, ``max|delta| / 127``; each pod's
+    delta rounded to int8 codes, which are replicated across the pod axis
+    (``gather``: an all-gather of int8) and summed locally in int32.
+    Summing before the collective would silently promote the wire traffic
+    to 4-byte ints. Otherwise the float32 mean."""
+    if compression != "int8":
+        return jnp.mean(delta, axis=0)
+    scale = jnp.max(jnp.abs(delta)) / 127.0 + 1e-12
+    q = jnp.clip(jnp.round(delta / scale), -127, 127).astype(jnp.int8)
+    # the barrier pins q as a materialised pod-sharded int8 tensor BEFORE
+    # the resharding constraint; without it the partitioner all-gathers
+    # the f32 delta and requantises per pod (4x the payload, observed in
+    # the lowered HLO)
+    q = gather(jax.lax.optimization_barrier(q))
+    total = jnp.sum(q.astype(jnp.int32), axis=0).astype(jnp.float32)
+    return total * scale / n_pods
 
 
 def bundle_for(kind: str, cfg: ModelConfig, shape: ShapeConfig, mesh,

@@ -19,7 +19,7 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.configs import ARCH_ORDER, get_config, smoke_config
 from repro.configs.base import SMOKE_MESH, ShapeConfig, TrainConfig
-from repro.data import lm_batch_iterator
+from repro.data import audio_stream, lm_batch_iterator
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_smoke_mesh
 from repro.launch.step_builders import make_train_step
@@ -33,7 +33,10 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true", default=True)
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--seq", type=int, default=64)
+    ap.add_argument("--seq", type=int, default=None,
+                    help="tokens per sequence (default 64); for an audio "
+                         "model, waveform samples (default 10,320: 32 "
+                         "frames)")
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--lr", type=float, default=1e-3)
@@ -41,6 +44,9 @@ def main(argv=None):
     enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    audio = cfg.family == "audio"
+    if args.seq is None:
+        args.seq = 10_320 if audio else 64
     shape = ShapeConfig(name="cli", seq_len=args.seq,
                         global_batch=args.batch, kind="train")
     train_cfg = TrainConfig(learning_rate=args.lr, warmup_steps=5,
@@ -61,18 +67,14 @@ def main(argv=None):
 
     step_fn = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
                       out_shardings=bundle.out_shardings)
-    data = lm_batch_iterator(0, args.batch, args.seq, cfg.vocab_size)
+    data = (audio_stream(cfg, 0, args.batch, args.seq) if audio else
+            lm_batch_iterator(0, args.batch, args.seq, cfg.vocab_size))
     losses = []
     t0 = time.time()
     with mesh:
         for step in range(start_step, args.steps):
             np_batch = next(data)
             batch = {k: jnp.asarray(v) for k, v in np_batch.items()}
-            if cfg.external_embeddings:
-                batch = {"embeds": jax.random.normal(
-                    jax.random.fold_in(rng, step),
-                    (args.batch, args.seq, cfg.d_model), jnp.bfloat16),
-                    "targets": batch["targets"]}
             if cfg.family == "vlm":
                 batch["image_embeds"] = jnp.zeros(
                     (args.batch, cfg.num_image_tokens, cfg.d_model),

@@ -121,6 +121,33 @@ def rms_norm(x, scale, eps: float = 1e-5):
     return (x * (1.0 + scale.astype(jnp.float32))).astype(dt)
 
 
+def layer_norm(x, scale, bias, eps: float = 1e-5):
+    """LayerNorm with a bias, statistics in float32. ``scale`` is stored
+    as its offset from 1, as ``rms_norm``'s is."""
+    dt = x.dtype
+    x = x.astype(jnp.float32)
+    mean = jnp.mean(x, axis=-1, keepdims=True)
+    x = x - mean
+    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    x = x * jax.lax.rsqrt(var + eps)
+    x = x * (1.0 + scale.astype(jnp.float32)) + bias.astype(jnp.float32)
+    return x.astype(dt)
+
+
+def norm_init(b: "Builder", name: str, cfg: ModelConfig):
+    """The block norm ``name`` (and ``<name>_bias`` for LayerNorm)."""
+    dt = _dtype(cfg.param_dtype)
+    b.add(name, zeros_init((cfg.d_model,), ("norm",), dt))
+    if cfg.norm == "layer":
+        b.add(f"{name}_bias", zeros_init((cfg.d_model,), ("norm",), dt))
+
+
+def norm_apply(p, name: str, x, cfg: ModelConfig):
+    if cfg.norm == "layer":
+        return layer_norm(x, p[name], p[f"{name}_bias"], cfg.norm_eps)
+    return rms_norm(x, p[name], cfg.norm_eps)
+
+
 def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim))
 
@@ -271,6 +298,11 @@ def attn_init(rng, cfg: ModelConfig, d_in: Optional[int] = None,
     b.add("wk", dense_init(ks[1], (d, hkv * hd), ("embed", "kv_heads"), dt))
     b.add("wv", dense_init(ks[2], (d, hkv * hd), ("embed", "kv_heads"), dt))
     b.add("wo", dense_init(ks[3], (hq * hd, d), ("heads", "embed"), dt))
+    if cfg.proj_bias:
+        b.add("bq", zeros_init((hq * hd,), ("norm",), dt))
+        b.add("bk", zeros_init((hkv * hd,), ("norm",), dt))
+        b.add("bv", zeros_init((hkv * hd,), ("norm",), dt))
+        b.add("bo", zeros_init((d,), ("norm",), dt))
     if cfg.qk_norm:
         b.add("q_norm", zeros_init((hd,), ("norm",), dt))
         b.add("k_norm", zeros_init((hd,), ("norm",), dt))
@@ -286,6 +318,9 @@ def attn_init(rng, cfg: ModelConfig, d_in: Optional[int] = None,
 def _proj_qkv(p, x, cfg: ModelConfig, lora_scope=None):
     def mm(name, w):
         y = jnp.einsum("bsd,df->bsf", x, w.astype(x.dtype))
+        bias = p.get("b" + name[1:])  # bq for wq, ...
+        if bias is not None:
+            y = y + bias.astype(x.dtype)
         if lora_scope is not None and f"{name}_lora_a" in p:
             a = lora_scope(p[f"{name}_lora_a"]).astype(x.dtype)
             bb = lora_scope(p[f"{name}_lora_b"]).astype(x.dtype)
@@ -307,21 +342,28 @@ def attn_apply(p, x, cfg: ModelConfig, *, positions, causal=None,
                block_causal=True, lora_scope=None):
     causal = cfg.causal if causal is None else causal
     q, k, v = _proj_qkv(p, x, cfg, lora_scope)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.rope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     o = flash_attention(q, k, v, causal=causal, q_chunk=cfg.attn_chunk,
                         kv_chunk=cfg.attn_chunk, block_causal=block_causal)
     b, s, _, _ = o.shape
     o = o.reshape(b, s, cfg.num_heads * cfg.head_dim)
-    return jnp.einsum("bsf,fd->bsd", o, p["wo"].astype(x.dtype))
+    return _out_proj(p, o, x.dtype)
+
+
+def _out_proj(p, o, dtype):
+    y = jnp.einsum("bsf,fd->bsd", o, p["wo"].astype(dtype))
+    return y + p["bo"].astype(dtype) if "bo" in p else y
 
 
 def attn_prefill(p, x, cfg: ModelConfig, *, positions, smax,
                  lora_scope=None):
     """Forward + return kv to seed a decode cache padded to smax."""
     q, k, v = _proj_qkv(p, x, cfg, lora_scope)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.rope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     o = flash_attention(q, k, v, causal=cfg.causal, q_chunk=cfg.attn_chunk,
                         kv_chunk=cfg.attn_chunk)
     b, s, _, _ = o.shape
@@ -329,7 +371,7 @@ def attn_prefill(p, x, cfg: ModelConfig, *, positions, smax,
     k_cache = jnp.pad(k, pad)
     v_cache = jnp.pad(v, pad)
     o = o.reshape(b, s, cfg.num_heads * cfg.head_dim)
-    return jnp.einsum("bsf,fd->bsd", o, p["wo"].astype(x.dtype)), (k_cache, v_cache)
+    return _out_proj(p, o, x.dtype), (k_cache, v_cache)
 
 
 def attn_decode(p, x, cache, cfg: ModelConfig, *, pos, lora_scope=None):
@@ -381,17 +423,23 @@ def mlp_init(rng, cfg: ModelConfig, d_ff: Optional[int] = None,
         b.add("w_gate", dense_init(ks[0], (d, ff), ("embed", "mlp"), dt))
     b.add("w_up", dense_init(ks[1], (d, ff), ("embed", "mlp"), dt))
     b.add("w_down", dense_init(ks[2], (ff, d), ("mlp", "embed"), dt))
+    if cfg.proj_bias:
+        b.add("b_up", zeros_init((ff,), ("norm",), dt))
+        b.add("b_down", zeros_init((d,), ("norm",), dt))
     return b.build()
 
 
 def mlp_apply(p, x):
     u = jnp.einsum("bsd,df->bsf", x, p["w_up"].astype(x.dtype))
+    if "b_up" in p:
+        u = u + p["b_up"].astype(x.dtype)
     if "w_gate" in p:
         g = jnp.einsum("bsd,df->bsf", x, p["w_gate"].astype(x.dtype))
         h = jax.nn.silu(g) * u
     else:
         h = jax.nn.gelu(u)
-    return jnp.einsum("bsf,fd->bsd", h, p["w_down"].astype(x.dtype))
+    y = jnp.einsum("bsf,fd->bsd", h, p["w_down"].astype(x.dtype))
+    return y + p["b_down"].astype(x.dtype) if "b_down" in p else y
 
 
 def moe_init(rng, cfg: ModelConfig):

@@ -12,11 +12,14 @@ from repro.sharding.rules import Sharder
 
 
 def build_model(cfg: ModelConfig, sharder: Optional[Sharder] = None):
+    from repro.models.hubert import HuBERT
     from repro.models.transformer import TransformerLM
     from repro.models.xlstm import XLSTMModel
     from repro.models.zamba import ZambaModel
 
-    if cfg.family in ("dense", "moe", "audio", "vlm"):
+    if cfg.family == "audio":
+        return HuBERT(cfg, sharder)
+    if cfg.family in ("dense", "moe", "vlm"):
         return TransformerLM(cfg, sharder)
     if cfg.family == "ssm":
         return XLSTMModel(cfg, sharder)

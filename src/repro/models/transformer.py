@@ -65,8 +65,8 @@ class TransformerLM:
         b = L.Builder()
         ks = jax.random.split(rng, 4)
         dt = jnp.dtype(cfg.param_dtype)
-        b.add("ln1", L.zeros_init((cfg.d_model,), ("norm",), dt))
-        b.add("ln2", L.zeros_init((cfg.d_model,), ("norm",), dt))
+        L.norm_init(b, "ln1", cfg)
+        L.norm_init(b, "ln2", cfg)
         if kind == "cross":
             b.sub("xattn", L.attn_init(ks[0], cfg))
             b.add("xgate", L.zeros_init((), (), dt))
@@ -85,15 +85,22 @@ class TransformerLM:
         """Returns (params, axes)."""
         cfg = self.cfg
         ks = jax.random.split(rng, len(self.segments) + 1)
-        params, axes = {}, {}
+        params, axes = self.init_segments(ks[1:])
         emb_p, emb_a = L.embed_init(ks[0], cfg)
         params["embed"], axes["embed"] = emb_p, emb_a
+        return params, axes
+
+    def init_segments(self, keys):
+        """The layer stacks alone, one key per segment: (params, axes)
+        keyed ``seg<i>``, as ``_stack_apply`` takes them."""
+        params, axes = {}, {}
         for si, (kinds, repeat) in enumerate(self.segments):
             seg_p, seg_a = {}, {}
             for bi, kind in enumerate(kinds):
                 def one(r, _kind=kind):
                     return self._block_init(r, _kind)
-                p, a = L.stack_init(one, jax.random.fold_in(ks[si + 1], bi), repeat)
+                p, a = L.stack_init(one, jax.random.fold_in(keys[si], bi),
+                                    repeat)
                 seg_p[f"b{bi}_{kind}"] = p
                 seg_a[f"b{bi}_{kind}"] = a
             params[f"seg{si}"] = seg_p
@@ -113,7 +120,7 @@ class TransformerLM:
                      causal=None):
         cfg = self.cfg
         shard = self.sharder
-        h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
+        h = L.norm_apply(p, "ln1", x, cfg)
         aux = jnp.zeros((), jnp.float32)
         if kind == "cross":
             a = L.cross_attn_apply(p["xattn"], h, image_embeds, cfg)
@@ -123,7 +130,7 @@ class TransformerLM:
                              causal=causal, block_causal=cfg.block_causal)
             x = x + a
         x = shard(x, ("batch", "seq", None))
-        h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+        h = L.norm_apply(p, "ln2", x, cfg)
         if kind == "moe":
             y, aux = L.moe_apply(p["moe"], h, cfg,
                                  group_size=cfg.moe_group_size,
@@ -237,7 +244,7 @@ class TransformerLM:
                 for bi, kind in enumerate(kinds):
                     key = f"b{bi}_{kind}"
                     p = layer_p[key]
-                    h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
+                    h = L.norm_apply(p, "ln1", x, cfg)
                     if kind == "cross":
                         # static image kv — attend, no cache update
                         o = _cross_decode(p["xattn"], h, layer_c[key], cfg)
@@ -248,7 +255,7 @@ class TransformerLM:
                                               pos=pos)
                         x = x + o
                         new_c[key] = kv
-                    h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+                    h = L.norm_apply(p, "ln2", x, cfg)
                     if kind == "moe":
                         y, _ = L.moe_apply(p["moe"], h, cfg,
                                            group_size=cfg.moe_group_size,

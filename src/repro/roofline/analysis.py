@@ -123,9 +123,13 @@ def model_flops_for(cfg, shape) -> float:
     from repro.models.registry import active_param_count
 
     n_active = active_param_count(cfg)
+    tokens = shape.tokens_per_step
+    if cfg.family == "audio":  # seq_len counts samples; the tokens are frames
+        from repro.models.hubert import frames_for
+        tokens = shape.global_batch * frames_for(cfg, shape.seq_len)
     if shape.kind == "train":
-        return 6.0 * n_active * shape.tokens_per_step
-    return 2.0 * n_active * shape.tokens_per_step
+        return 6.0 * n_active * tokens
+    return 2.0 * n_active * tokens
 
 
 def analyze(compiled, *, arch: str, shape, kind: str, mesh_name: str,

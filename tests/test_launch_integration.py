@@ -22,7 +22,9 @@ pytestmark = pytest.mark.slow  # minutes-long; PR CI runs -m 'not slow'
 def test_bundle_lowers_and_compiles(arch, kind):
     cfg = smoke_config(arch)
     mesh = make_smoke_mesh()
-    shape = ShapeConfig(name="t", seq_len=32,
+    # an audio model takes waveforms: seq_len counts samples (20 frames)
+    shape = ShapeConfig(name="t",
+                        seq_len=6_480 if cfg.family == "audio" else 32,
                         global_batch=4, kind=kind)
     b = bundle_for(kind, cfg, shape, mesh, SMOKE_MESH,
                    TrainConfig(microbatches=2 if kind == "train" else 1))
